@@ -1,20 +1,23 @@
 # One function per paper table/figure. Prints ``figure,metric,policy,value``
 # CSV rows; roofline terms are derived from the dry-run artifacts when
 # present (run ``python -m repro.launch.dryrun --all`` first for those).
+# Exits non-zero when any figure raised.
 from __future__ import annotations
 
 import os
+import sys
 import time
 import traceback
 
 
-def main() -> None:
+def main() -> int:
     from benchmarks import figures
     from benchmarks.common import emit
     from repro.common.cache import enable_compilation_cache
 
     enable_compilation_cache()   # repeat runs skip the XLA cold compiles
     t00 = time.time()
+    failed = []
     print("figure,metric,policy,value")
     for fn in (figures.fig3_incast,
                figures.fig4_single_switch_collectives,
@@ -31,6 +34,7 @@ def main() -> None:
         except Exception:
             print(f"{fn.__name__},ERROR,-,1")
             traceback.print_exc()
+            failed.append(fn.__name__)
         emit([(fn.__name__, "wall_s", "-", round(time.time() - t0, 1))])
 
     # engine-step roofline: analytic, always available
@@ -44,7 +48,11 @@ def main() -> None:
     else:
         print("roofline,SKIPPED (run: python -m repro.launch.dryrun --all)")
     emit([("all", "total_wall_s", "-", round(time.time() - t00, 1))])
+    if failed:
+        print(f"figures failed: {', '.join(failed)}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

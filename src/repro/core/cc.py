@@ -47,6 +47,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 # python float, not a jnp scalar: policy updates run inside Pallas kernel
@@ -245,6 +246,19 @@ _DCQCN_SPECS = {
 }
 
 
+def _dcqcn_jitter(n_flows: int) -> jnp.ndarray:
+    """A fixed per-flow hash in [0.9, 1.1), computed on the host so that
+    every backend gets the same values: in-graph, XLA:TPU's f32 remainder
+    is inexact once ``k * 7919`` passes 2**24.  The values are those of
+    the f32 expression ``0.9 + 0.2 * ((k * 7919 % 97) / 97)`` as XLA:CPU
+    evaluates it, with the final multiply-add fused (rounded once)."""
+    k = np.arange(n_flows, dtype=np.float32)
+    q = (k * np.float32(7919)) % np.float32(97) / np.float32(97.0)
+    fused = (np.float64(np.float32(0.9))
+             + np.float64(np.float32(0.2)) * q.astype(np.float64))
+    return jnp.asarray(fused.astype(np.float32))
+
+
 def make_dcqcn(g: float = 1 / 256, rai_frac: float = 0.03,
                rhai_frac: float = 0.05, timer: float = 55e-6,
                cut_gap: float = 50e-6, fast_rounds: int = 5,
@@ -258,7 +272,7 @@ def make_dcqcn(g: float = 1 / 256, rai_frac: float = 0.03,
         F, line = ctx.n_flows, ctx.line
         # +-10% per-flow timer jitter: desynchronizes the post-cut sawtooth
         # (packet simulators get this for free from stochastic marking).
-        jit = 0.9 + 0.2 * ((jnp.arange(F, dtype=jnp.float32) * 7919 % 97) / 97.0)
+        jit = _dcqcn_jitter(F)
         return {
             "rc": _full(F, 0) + line, "rt": _full(F, 0) + line,
             "alpha": _full(F, 1.0), "jit": jit,

@@ -103,11 +103,12 @@ class EngineConfig:
     chunk_steps: int = 256        # early-exit check granularity (in-jit)
     queue_stride: int = 1         # record dev_queue every k steps; 0 = off
     # step backend: "auto" resolves per jax.default_backend() — the fused
-    # Pallas engine-step kernels (repro.kernels.engine_step) on TPU/GPU,
-    # the historical jnp path elsewhere, so CPU results stay bitwise
-    # identical to the engine goldens.  "pallas" forces the kernel path
+    # Pallas engine-step kernel (repro.kernels.engine_step) on TPU, the
+    # historical jnp path elsewhere, so CPU results stay bitwise identical
+    # to the engine goldens.  "pallas" forces the kernel path
     # (interpret-mode off-TPU: the CI correctness configuration); "jnp"
-    # forces the reference path on any backend.
+    # forces the reference path on any backend.  ``effective_step_impl``
+    # says which step a given policy actually runs.
     step_impl: str = "auto"       # "auto" | "jnp" | "pallas"
     # run-health detection (observers only; never change simulated physics)
     deadlock_check_every: int = 64   # pause-cycle check cadence (steps)
@@ -197,17 +198,46 @@ def _per_class(v):
     return jnp.broadcast_to(jnp.asarray(v, jnp.float32), (N_LINK_CLASSES,))
 
 
+def _fabric_tables(pp, fab: FabricParams, flt, faulty: bool) -> dict:
+    """Per-hop (F, MAXHOP) and per-link (Lk+1,) values of the per-class
+    fabric and fault knobs.  They are the same at every step, so each run
+    gathers them once, outside the step loop."""
+    def hop(v):
+        return _per_class(v)[pp["cls_path"]]
+
+    def link(v):
+        return _per_class(v)[pp["link_class"]]
+
+    tab = dict(kmin_h=hop(fab.kmin), kmax_h=hop(fab.kmax),
+               pmax_h=hop(fab.pmax), xoff_l=link(fab.xoff),
+               xon_l=link(fab.xon))
+    if faulty:
+        tab.update(ecn_scale_h=hop(flt.ecn_scale), degrade_l=link(flt.degrade),
+                   loss_h=hop(flt.loss_rate), pfc_on_l=link(flt.pfc_on))
+    return tab
+
+
 def resolve_step_impl(cfg: EngineConfig) -> str:
     """Backend dispatch for the engine step: "auto" picks the fused Pallas
-    kernels on accelerator backends and the jnp reference path on CPU (so
-    the default path reproduces the engine goldens bitwise there)."""
+    kernel on TPU and the jnp reference path elsewhere (so the default
+    path reproduces the engine goldens bitwise on CPU)."""
     impl = cfg.step_impl
     if impl == "auto":
-        return "pallas" if jax.default_backend() in ("tpu", "gpu") else "jnp"
+        return "pallas" if jax.default_backend() == "tpu" else "jnp"
     if impl not in ("jnp", "pallas"):
         raise ValueError(f"step_impl must be 'auto', 'jnp' or 'pallas', "
                          f"got {impl!r}")
     return impl
+
+
+def effective_step_impl(policy: Policy, cfg: EngineConfig) -> str:
+    """The step ``policy`` actually runs under ``cfg``: "pallas" only when
+    the resolved impl is "pallas" and the policy's update fits the
+    kernel's flat-array form; stacked product policies (tuple state +
+    ``lax.switch``) always run the jnp step."""
+    if resolve_step_impl(cfg) == "pallas" and kernel_eligible(policy):
+        return "pallas"
+    return "jnp"
 
 
 def _cfg_static(cfg: EngineConfig) -> EngineConfig:
@@ -560,7 +590,7 @@ def _init_carry(pp, plan: _Plan, policy: Policy, cfg: EngineConfig,
 
 
 def _make_step(policy: Policy, cfg: EngineConfig, plan: _Plan,
-               faulty: bool = False):
+               faulty: bool = False, batched: bool = False):
     dt = cfg.dt
     Lk = plan.n_links
     stride = cfg.queue_stride
@@ -570,29 +600,16 @@ def _make_step(policy: Policy, cfg: EngineConfig, plan: _Plan,
     # covers paths of length up to 2^k, so ceil(log2(D)) rounds suffice
     dl_rounds = max(1, (max(D, 2) - 1).bit_length())
 
-    # backend dispatch: route stages 1-2 (+ the gather reductions and the
-    # PFC pause signal) through the fused Pallas engine-step kernels when
-    # the resolved impl is "pallas" and the policy's update is expressible
-    # in the kernel's flat-array form; stacked product policies (tuple
-    # state + lax.switch) stay on the jnp path.  The jnp branch below is
-    # the historical step, emitted unchanged — goldens stay bitwise.
-    use_kernel = (resolve_step_impl(cfg) == "pallas"
-                  and kernel_eligible(policy))
+    # backend dispatch: route stages 1-2 through the fused Pallas
+    # engine-step kernel (see ``effective_step_impl``).  Every other stage,
+    # the segment reductions included, is the same XLA code on both
+    # paths; the jnp branch below is the historical step, emitted
+    # unchanged — goldens stay bitwise.
+    use_kernel = effective_step_impl(policy, cfg) == "pallas"
     if use_kernel:
-        from repro.kernels import default_interpret
         from repro.kernels.engine_step import ops as es_ops
-        interpret = default_interpret(None)
 
-        def reduce_(strategy, arrs, vals):
-            if strategy[0] == "gather":
-                return es_ops.segment_reduce(vals, arrs["idx"], strategy[1],
-                                             strategy[2],
-                                             interpret=interpret)
-            return _reduce(strategy, arrs, vals)
-    else:
-        reduce_ = _reduce
-
-    def step(carry, it, pp, cc_params, fab, flt):
+    def step(carry, it, pp, cc_params, flt, tab):
         def _pause_cycle(paused):
             """Any cycle in the switch->switch PFC wait-for graph?  Link l
             paused means src_dev(l) waits on dst_dev(l) to resume."""
@@ -607,10 +624,10 @@ def _make_step(policy: Policy, cfg: EngineConfig, plan: _Plan,
         wire = _wire_of(policy, cc_params)
         path, hopmask = pp["path"], pp["hopmask"]
         t = it.astype(jnp.float32) * dt
-        # per-link-class fabric knobs (scalar leaves broadcast to uniform)
-        kmin_h = _per_class(fab.kmin)[pp["cls_path"]]     # (F, MAXHOP)
-        kmax_h = _per_class(fab.kmax)[pp["cls_path"]]
-        pmax_h = _per_class(fab.pmax)[pp["cls_path"]]
+        # the end of this step's transfer window, rounded once on every
+        # backend: dependents compare their start time against it exactly
+        t_end = (it + 1).astype(jnp.float32) * dt
+        kmin_h, kmax_h, pmax_h = tab["kmin_h"], tab["kmax_h"], tab["pmax_h"]
         # ---- 1. delayed signals ------------------------------------------
         idx = jnp.maximum(it - pp["delay_steps"], 0) % plan.ring
         flat = idx[:, None] * (Lk + 1) + path            # (F, MAXHOP)
@@ -623,7 +640,7 @@ def _make_step(policy: Policy, cfg: EngineConfig, plan: _Plan,
             # product as the jnp path's post-clip scale)
             pmax_eff = pmax_h
             if faulty:
-                pmax_eff = pmax_eff * _per_class(flt.ecn_scale)[pp["cls_path"]]
+                pmax_eff = pmax_eff * tab["ecn_scale_h"]
             loss = (carry["loss_sig"] if faulty
                     else jnp.zeros_like(pp["line"]))
             cc, rate, win = es_ops.fused_step(
@@ -632,14 +649,14 @@ def _make_step(policy: Policy, cfg: EngineConfig, plan: _Plan,
                 kmin_h=kmin_h, kmax_h=kmax_h, pmax_h=pmax_eff,
                 base_rtt=pp["base_rtt"], line=pp["line"], loss=loss,
                 state=carry["cc"], params=cc_params, t=t, dt=dt,
-                t_base_util=cfg.t_base_util, interpret=interpret)
+                t_base_util=cfg.t_base_util)
         else:
             rtt = pp["base_rtt"] + (q_d / caps * hopmask).sum(1)
             mark = jnp.clip((q_d - kmin_h) / jnp.maximum(kmax_h - kmin_h, 1.0),
                             0.0, 1.0) * pmax_h
             if faulty:
                 # ECN misconfiguration: scale marking probability (0 = broken)
-                mark = mark * _per_class(flt.ecn_scale)[pp["cls_path"]]
+                mark = mark * tab["ecn_scale_h"]
             mark = mark * pp["ecn_mask"]
             ecn = 1.0 - jnp.prod(1.0 - mark, axis=1)
             util_l = tx_d / caps + q_d / (caps * cfg.t_base_util)
@@ -680,7 +697,7 @@ def _make_step(policy: Policy, cfg: EngineConfig, plan: _Plan,
             # time-scheduled capacity faults on fabric links: degradation
             # windows and periodic link flaps (down for flap_down out of
             # every flap_period seconds)
-            deg = _per_class(flt.degrade)[pp["link_class"]]
+            deg = tab["degrade_l"]
             in_deg = (t >= flt.degrade_t0) & (t < flt.degrade_t1)
             capmul = jnp.where(in_deg & (pp["fabric_link"] > 0), deg, 1.0)
             period = jnp.asarray(flt.flap_period, jnp.float32)
@@ -697,13 +714,12 @@ def _make_step(policy: Policy, cfg: EngineConfig, plan: _Plan,
         tx_bytes = jnp.zeros(Lk + 1, jnp.float32)
         if faulty:
             # per-hop drop probability: fabric links only (NVLink lossless)
-            loss_p = (_per_class(flt.loss_rate)[pp["cls_path"]]
-                      * pp["fabric_path"])
+            loss_p = tab["loss_h"] * pp["fabric_path"]
             lost_step = jnp.zeros_like(carry["lost"])
         for h in range(MAXHOP):
             if plan.hop[h][0] == "empty":   # no flow ever uses this hop slot
                 continue
-            dem = reduce_(plan.hop[h], pp["r_hop"][h], backlog[:, h])
+            dem = _reduce(plan.hop[h], pp["r_hop"][h], backlog[:, h])
             frac = jnp.where(dem > 0,
                              jnp.minimum(1.0, rem_cap / jnp.maximum(dem, 1e-9)),
                              0.0)
@@ -751,33 +767,25 @@ def _make_step(policy: Policy, cfg: EngineConfig, plan: _Plan,
                                  carry["loss_sig"])
 
         # ---- 6. queues ------------------------------------------------------
-        q_link = reduce_(plan.qlink, pp["r_qlink"], backlog.reshape(-1))
-        xoff_l = _per_class(fab.xoff)[pp["link_class"]]   # (Lk+1,)
-        xon_l = _per_class(fab.xon)[pp["link_class"]]
+        q_link = _reduce(plan.qlink, pp["r_qlink"], backlog.reshape(-1))
+        xoff_l, xon_l = tab["xoff_l"], tab["xon_l"]
         can = pp["can_pause"]
         if faulty:
             # PFC misconfiguration / lossy-RoCE: pfc_on=0 disables pausing
-            can = can & (_per_class(flt.pfc_on)[pp["link_class"]] > 0.5)
-        if use_kernel and plan.qport[0] == "gather":
-            # ---- 6b+7 fused: per-port occupancy reduction + hysteresis --
-            q_port, paused = es_ops.segment_reduce_pfc(
-                backlog.reshape(-1), pp["r_qport"]["idx"], plan.qport[1],
-                plan.qport[2], xoff_l, xon_l, can, carry["paused"],
-                interpret=interpret)
-        else:
-            # per-ingress-port occupancy at the receiving switch
-            q_port = reduce_(plan.qport, pp["r_qport"], backlog.reshape(-1))
+            can = can & (tab["pfc_on_l"] > 0.5)
+        # per-ingress-port occupancy at the receiving switch
+        q_port = _reduce(plan.qport, pp["r_qport"], backlog.reshape(-1))
 
-            # ---- 7. PFC per-port hysteresis ---------------------------------
-            over = (q_port > xoff_l) & can
-            under = q_port < xon_l
-            paused = jnp.where(over, True,
-                               jnp.where(under, False, carry["paused"]))
+        # ---- 7. PFC per-port hysteresis -------------------------------------
+        over = (q_port > xoff_l) & can
+        under = q_port < xon_l
+        paused = jnp.where(over, True,
+                           jnp.where(under, False, carry["paused"]))
         # PAUSE frames: one on the off-transition + periodic refreshes while
         # the port stays paused (how NS3 counts them)
         frames = ((paused & ~carry["paused"])[:Lk].astype(jnp.float32)
                   + paused[:Lk].astype(jnp.float32) * (dt / cfg.pause_resend))
-        pause_count = carry["pause_count"] + reduce_(plan.pause, pp["r_pause"],
+        pause_count = carry["pause_count"] + _reduce(plan.pause, pp["r_pause"],
                                                      frames)
 
         # ---- 8. completion --------------------------------------------------
@@ -792,11 +800,11 @@ def _make_step(policy: Policy, cfg: EngineConfig, plan: _Plan,
         newly = ~carry["done"] & (jnp.where(pp["n_hops"] > 0, data_done, marker_done))
         done = carry["done"] | newly
         # completion happens at the END of this step's transfer window
-        t_finish = jnp.where(newly, t + dt, carry["t_finish"])
-        g_count = carry["g_count"] + reduce_(plan.group, pp["r_group"],
+        t_finish = jnp.where(newly, t_end, carry["t_finish"])
+        g_count = carry["g_count"] + _reduce(plan.group, pp["r_group"],
                                              newly.astype(jnp.float32))
         g_done_new = (g_count >= pp["gsize"] - 0.5) & ~(carry["g_count"] >= pp["gsize"] - 0.5)
-        g_time = jnp.where(g_done_new, t + dt, carry["g_time"])
+        g_time = jnp.where(g_done_new, t_end, carry["g_time"])
 
         # ---- 9. history + soft cost ----------------------------------------
         hist_q = lax.dynamic_update_slice_in_dim(
@@ -826,8 +834,12 @@ def _make_step(policy: Policy, cfg: EngineConfig, plan: _Plan,
         dl_candidates = jnp.any(paused[:Lk] & pp["sw_sw"])
         do_check = ((it % cfg.deadlock_check_every == 0) & dl_candidates
                     & (carry["deadlock_step"] < 0))
-        cycle = lax.cond(do_check, _pause_cycle,
-                         lambda _: jnp.zeros((), bool), paused)
+        if batched:
+            # per-lane predicate: select, see _make_run's lane_axis
+            cycle = do_check & _pause_cycle(paused)
+        else:
+            cycle = lax.cond(do_check, _pause_cycle,
+                             lambda _: jnp.zeros((), bool), paused)
         deadlock_step = jnp.where(cycle & (carry["deadlock_step"] < 0),
                                   it, carry["deadlock_step"])
         # non-finite guard: freeze the lane at the first bad state instead
@@ -851,7 +863,7 @@ def _make_step(policy: Policy, cfg: EngineConfig, plan: _Plan,
             new_carry["loss_sig"] = loss_sig
         if stride > 0:
             # strided timeline recording; rows for skipped steps are dropped
-            q_dev = reduce_(plan.qdev, pp["r_qdev"], q_link[:Lk])
+            q_dev = _reduce(plan.qdev, pp["r_qdev"], q_link[:Lk])
             row = jnp.where(it % stride == 0, it // stride, n_qrows)
             new_carry["qbuf"] = carry["qbuf"].at[row].set(q_dev, mode="drop")
         return new_carry
@@ -860,7 +872,8 @@ def _make_step(policy: Policy, cfg: EngineConfig, plan: _Plan,
 
 
 def _make_run(policy: Policy, cfg: EngineConfig, plan: _Plan,
-              early_exit: bool, faulty: bool = False, remat: bool = False):
+              early_exit: bool, faulty: bool = False, remat: bool = False,
+              lane_axis: str | None = None):
     """Build the full (jittable) stepping loop.
 
     Each step is gated on ``done.all() | diverged | (it >= total)`` so
@@ -876,21 +889,35 @@ def _make_run(policy: Policy, cfg: EngineConfig, plan: _Plan,
     memory for long-horizon gradients (the ``repro.learn`` trainer's
     path).  The forward computation is the same gated step sequence, so
     forward values match the monolithic scan exactly.
+
+    ``lane_axis`` names the vmap axis when the run is vmapped over sweep
+    lanes.  A predicate that differs per lane turns ``lax.cond`` into a
+    select whose operands JAX broadcasts per lane, the scenario's index
+    arrays included, and XLA:TPU then gathers element by element.  So
+    batched lanes select the gated step explicitly, and the early-exit
+    loop runs while any lane is live (``lax.pmax`` over the axis): its
+    step counter, and the indices derived from it, stay shared.  Carries
+    are the same as unbatched.
     """
     if remat and early_exit:
         raise ValueError("remat applies to the fixed-length scan only "
                          "(early_exit=False): lax.while_loop is not "
                          "reverse-mode differentiable anyway")
-    step = _make_step(policy, cfg, plan, faulty)
+    step = _make_step(policy, cfg, plan, faulty, batched=lane_axis is not None)
     total = cfg.max_steps * (cfg.max_extends + 1)
     chunk = max(1, min(cfg.chunk_steps, total))
 
     def run(carry, pp, cc_params, fab, flt):
+        tab = _fabric_tables(pp, fab, flt, faulty)
+
         def body(c, it):
-            c2 = lax.cond(jnp.all(c["done"]) | c["diverged"] | (it >= total),
-                          lambda c: c,
-                          lambda c: step(c, it, pp, cc_params, fab, flt),
-                          c)
+            skip = jnp.all(c["done"]) | c["diverged"] | (it >= total)
+            if lane_axis is not None:
+                new = step(c, it, pp, cc_params, flt, tab)
+                return jax.tree.map(lambda old, n: jnp.where(skip, old, n),
+                                    c, new), None
+            c2 = lax.cond(skip, lambda c: c,
+                          lambda c: step(c, it, pp, cc_params, flt, tab), c)
             return c2, None
 
         if not early_exit:
@@ -920,7 +947,10 @@ def _make_run(policy: Policy, cfg: EngineConfig, plan: _Plan,
 
         def w_cond(state):
             c, it0 = state
-            return (~(jnp.all(c["done"]) | c["diverged"])) & (it0 < total)
+            live = (~(jnp.all(c["done"]) | c["diverged"])) & (it0 < total)
+            if lane_axis is not None:
+                live = lax.pmax(live.astype(jnp.int32), lane_axis) > 0
+            return live
 
         carry2, it_end = lax.while_loop(w_cond, w_body, (carry, jnp.int32(0)))
         return carry2, jnp.minimum(it_end, total)
@@ -985,9 +1015,8 @@ class Simulator:
         self.pp, self.plan = _prep(topo, sched, cfg, pad_flows, pad_groups)
         self._soft_jit = None
 
-    def run(self, cc_params: dict | None = None, early_exit: bool = True,
-            fabric_params: FabricParams | None = None,
-            fault_spec: FaultSpec | None = None) -> Results:
+    def _call(self, cc_params, early_exit, fabric_params, fault_spec):
+        """The jitted stepping loop ``run`` dispatches, and its arguments."""
         params = cc_params if cc_params is not None else self.policy.params
         fab = fabric_params if fabric_params is not None else self.fabric
         flt = fault_spec if fault_spec is not None else self.fault
@@ -996,7 +1025,25 @@ class Simulator:
                           faulty)
         carry = _init_carry(self.pp, self.plan, self.policy, self.cfg,
                             params, faulty)
-        carry, steps = fn(carry, self.pp, params, fab, flt)
+        return fn, (carry, self.pp, params, fab, flt)
+
+    def compile(self, cc_params: dict | None = None, early_exit: bool = True,
+                fabric_params: FabricParams | None = None,
+                fault_spec: FaultSpec | None = None) -> jax.stages.Compiled:
+        """Ahead-of-time compile the executable ``run`` dispatches for the
+        same arguments, so compile time is measured apart from the run and
+        the executable can be inspected (``as_text()``); the ``run`` that
+        follows finds it compiled."""
+        fn, args = self._call(cc_params, early_exit, fabric_params,
+                              fault_spec)
+        return fn.lower(*args).compile()
+
+    def run(self, cc_params: dict | None = None, early_exit: bool = True,
+            fabric_params: FabricParams | None = None,
+            fault_spec: FaultSpec | None = None) -> Results:
+        fn, args = self._call(cc_params, early_exit, fabric_params,
+                              fault_spec)
+        carry, steps = fn(*args)
         return self._results(carry, int(steps))
 
     def _results(self, carry, steps_run: int) -> Results:

@@ -74,9 +74,9 @@ import warnings
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
+from repro.common import cache as cache_mod
 from repro.common.sharding import resolve_grid_mesh
 from repro.core import cc as cc_mod
 from repro.core.cc import Policy, stack_policies
@@ -228,19 +228,23 @@ def _warn_unhealthy_lanes(batch: "BatchResults", B: int) -> None:
         RuntimeWarning, stacklevel=3)
 
 
+_LANES = "lanes"      # the vmap axis of sweep lanes (engine._make_run)
+
+
 def _one_lane(policy: Policy, cfg: EngineConfig, plan, faulty: bool):
-    """The per-lane body both batch paths vmap over: build a fresh carry,
-    run the jitted stepping loop (which donates it internally) and keep
-    only the per-lane finals."""
-    run = _make_run(policy, cfg, plan, early_exit=True, faulty=faulty)
+    """The per-lane body both batch paths vmap over (axis ``_LANES``):
+    build a fresh carry, run the jitted stepping loop (which donates it
+    internally) and keep only the per-lane finals."""
+    run = _make_run(policy, cfg, plan, early_exit=True, faulty=faulty,
+                    lane_axis=_LANES)
 
     def one(pp, params, fab, flt):
         carry = _init_carry(pp, plan, policy, cfg, params, faulty)
-        carry, steps = run(carry, pp, params, fab, flt)
+        carry, _ = run(carry, pp, params, fab, flt)
         out = {"t_finish": carry["t_finish"], "done": carry["done"],
                "pause_count": carry["pause_count"],
                "delivered": carry["delivered"], "soft": carry["soft"],
-               "steps": steps, "diverged": carry["diverged"],
+               "diverged": carry["diverged"],
                "deadlock_step": carry["deadlock_step"],
                "storm_step": carry["storm_step"]}
         if faulty:
@@ -260,9 +264,9 @@ def _compiled_batch(policy: Policy, cfg: EngineConfig, plan,
     fn = _BATCH_CACHE.get(key)
     if fn is None:
         one = _one_lane(policy, cfg, plan, faulty)
-        fn = _cache_put(_BATCH_CACHE, key,
-                        jax.jit(jax.vmap(one, in_axes=(None, 0, 0, 0))),
-                        "batch", BATCH_CACHE_MAX)
+        vm = jax.vmap(one, in_axes=(None, 0, 0, 0), axis_name=_LANES)
+        fn = _cache_put(_BATCH_CACHE, key, jax.jit(vm), "batch",
+                        BATCH_CACHE_MAX)
     return fn
 
 
@@ -285,12 +289,13 @@ def _compiled_sharded_batch(policy: Policy, cfg: EngineConfig, plan,
     fn = _SHARD_CACHE.get(key)
     if fn is None:
         one = _one_lane(policy, cfg, plan, faulty)
-        vm = jax.vmap(one, in_axes=(None, 0, 0, 0))
+        vm = jax.vmap(one, in_axes=(None, 0, 0, 0), axis_name=_LANES)
         axis = mesh.axis_names[0]
         lanes = PartitionSpec(axis)
-        sharded = shard_map(vm, mesh=mesh,
-                            in_specs=(PartitionSpec(), lanes, lanes, lanes),
-                            out_specs=lanes, check_rep=False)
+        sharded = jax.shard_map(vm, mesh=mesh,
+                                in_specs=(PartitionSpec(), lanes, lanes,
+                                          lanes),
+                                out_specs=lanes, check_vma=False)
         fn = _cache_put(_SHARD_CACHE, key, jax.jit(sharded),
                         "shard", SHARD_CACHE_MAX)
     return fn
@@ -494,10 +499,11 @@ _NO_DISK: set = set()
 def calibration_cache_path(backend: str | None = None,
                            cache_dir: str | None = None) -> str:
     """Where ``calibrate_backend`` persists its measured table
-    (``$REPRO_CACHE_DIR/repro_calibration_<backend>.json``, default
-    ``.cache/``) so fresh processes warm-start instead of re-measuring."""
+    (``<checkout>/.cache/repro_calibration_<backend>.json``, the fixed
+    ``repro.common.cache.CACHE_ROOT``) so fresh processes warm-start
+    instead of re-measuring."""
     backend = backend or jax.default_backend()
-    cache_dir = cache_dir or os.environ.get("REPRO_CACHE_DIR", ".cache")
+    cache_dir = cache_dir or cache_mod.CACHE_ROOT
     return os.path.join(cache_dir, f"repro_calibration_{backend}.json")
 
 
@@ -1068,6 +1074,63 @@ class SweepRunner:
             return parts[0]
         return jax.tree.map(lambda *xs: np.concatenate(xs, axis=0), *parts)
 
+    def _batch_inputs(self, topo, sched, policy, stacked_params,
+                      stacked_fabric, fabric_params, cc_params, cfg,
+                      stacked_fault, fault_spec):
+        """Validate and stack ``run_batch``'s arguments: returns ``(policy,
+        cfg, sim, full, fab, flt, faulty, B)``."""
+        policy = _resolve(policy)
+        stacked_params = stacked_params or {}
+        policy.check_tunable(stacked_params)
+        if cc_params:
+            policy.check_tunable(cc_params)
+        sizes = [len(np.asarray(v)) for v in stacked_params.values()]
+        sizes += [np.asarray(v).shape[0] for v in (stacked_fabric or {}).values()]
+        sizes += [np.asarray(v).shape[0] for v in (stacked_fault or {}).values()]
+        if not sizes:
+            raise ValueError("empty batch: provide stacked_params, "
+                             "stacked_fabric and/or stacked_fault")
+        if len(set(sizes)) > 1:
+            raise ValueError(f"inconsistent batch sizes {sorted(set(sizes))}")
+        B = sizes[0]
+        base_cc = dict(policy.params, **(cc_params or {}))
+        full = {k: np.asarray(stacked_params.get(k, np.full(B, float(v))),
+                              np.float32)
+                for k, v in base_cc.items()}
+        cfg = dataclasses.replace(cfg or self.cfg, queue_stride=0)
+        fab = _stack_fabric(_as_fabric(fabric_params, cfg), stacked_fabric, B)
+        flt = _stack_fault(_as_fault(fault_spec), stacked_fault, B)
+        faulty = is_faulty(flt)
+        sim = self.simulator(topo, sched, policy, cfg)
+        return policy, cfg, sim, full, fab, flt, faulty, B
+
+    def compile_batch(self, topo, sched, policy: Policy | str,
+                      stacked_params: dict | None = None,
+                      stacked_fabric: dict | None = None,
+                      fabric_params: FabricParams | None = None,
+                      cc_params: dict | None = None,
+                      cfg: EngineConfig | None = None,
+                      stacked_fault: dict | None = None,
+                      fault_spec: FaultSpec | None = None
+                      ) -> jax.stages.Compiled:
+        """Ahead-of-time compile the executable ``run_batch`` dispatches
+        for the same arguments (one chunk of lanes, on this runner's mesh
+        if it has one), so compile time is measured apart from the run
+        and the executable can be inspected (``as_text()``,
+        ``output_shardings``); the ``run_batch`` that follows finds it
+        compiled."""
+        policy, cfg, sim, full, fab, flt, faulty, B = self._batch_inputs(
+            topo, sched, policy, stacked_params, stacked_fabric,
+            fabric_params, cc_params, cfg, stacked_fault, fault_spec)
+        take = np.minimum(np.arange(self._chunk_size(B)), B - 1)
+        lanes = jax.tree.map(lambda a: np.asarray(a)[take], (full, fab, flt))
+        if self.mesh is None:
+            fn = _compiled_batch(policy, cfg, sim.plan, faulty)
+        else:
+            fn = _compiled_sharded_batch(policy, cfg, sim.plan, faulty,
+                                         self.mesh)
+        return fn.lower(sim.pp, *lanes).compile()
+
     def run_batch(self, topo, sched, policy: Policy | str,
                   stacked_params: dict | None = None,
                   stacked_fabric: dict | None = None,
@@ -1095,29 +1158,9 @@ class SweepRunner:
         deadlocked or budget-exhausted lane is flagged, and the healthy
         lanes complete normally — see ``BatchResults.lane_status``.
         """
-        policy = _resolve(policy)
-        stacked_params = stacked_params or {}
-        policy.check_tunable(stacked_params)
-        if cc_params:
-            policy.check_tunable(cc_params)
-        sizes = [len(np.asarray(v)) for v in stacked_params.values()]
-        sizes += [np.asarray(v).shape[0] for v in (stacked_fabric or {}).values()]
-        sizes += [np.asarray(v).shape[0] for v in (stacked_fault or {}).values()]
-        if not sizes:
-            raise ValueError("empty batch: provide stacked_params, "
-                             "stacked_fabric and/or stacked_fault")
-        if len(set(sizes)) > 1:
-            raise ValueError(f"inconsistent batch sizes {sorted(set(sizes))}")
-        B = sizes[0]
-        base_cc = dict(policy.params, **(cc_params or {}))
-        full = {k: np.asarray(stacked_params.get(k, np.full(B, float(v))),
-                              np.float32)
-                for k, v in base_cc.items()}
-        cfg = dataclasses.replace(cfg or self.cfg, queue_stride=0)
-        fab = _stack_fabric(_as_fabric(fabric_params, cfg), stacked_fabric, B)
-        flt = _stack_fault(_as_fault(fault_spec), stacked_fault, B)
-        faulty = is_faulty(flt)
-        sim = self.simulator(topo, sched, policy, cfg)
+        policy, cfg, sim, full, fab, flt, faulty, B = self._batch_inputs(
+            topo, sched, policy, stacked_params, stacked_fabric,
+            fabric_params, cc_params, cfg, stacked_fault, fault_spec)
         out = self._dispatch_lanes(policy, cfg, sim, full, fab, flt,
                                    faulty, B)
         F = sim.plan.n_flows

@@ -3,9 +3,9 @@
 #   flash_decode  — chunked-KV decode attention (serving shape cells)
 #   cc_update     — fused DCQCN per-flow state update (the simulator's
 #                   inner loop when sweeping CC configs on-TPU)
-#   engine_step   — fused engine signals + generic policy update and the
-#                   padded-gather segment reduction (the simulator's full
-#                   stage-1/2 hot loop; see repro.core.engine step_impl)
+#   engine_step   — fused engine signals + generic policy update (the
+#                   simulator's stage-1/2 hot loop; see repro.core.engine
+#                   step_impl)
 # Each has ops.py (jit wrapper) + ref.py (pure-jnp oracle) + allclose tests.
 from __future__ import annotations
 
@@ -16,8 +16,8 @@ def default_interpret(interpret: bool | None = None) -> bool:
     """Resolve the kernel ``interpret`` convention.
 
     ``None`` (the default everywhere) auto-detects: compiled Mosaic on TPU,
-    interpret mode elsewhere (CPU test runs, GPU without Mosaic lowering).
-    Pass an explicit bool to force either path.
+    interpret mode elsewhere (the CPU test runs).  Pass an explicit bool
+    to force either path.
     """
     if interpret is not None:
         return bool(interpret)

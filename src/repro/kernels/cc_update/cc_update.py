@@ -15,6 +15,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import default_interpret
+
 
 def _kernel(rc_ref, rt_ref, alpha_ref, tcut_ref, tinc_ref, talpha_ref,
             cnt_ref, jit_ref, ecn_ref, line_ref, t_ref,
@@ -59,10 +61,13 @@ def _kernel(rc_ref, rt_ref, alpha_ref, tcut_ref, tinc_ref, talpha_ref,
 
 @functools.partial(jax.jit, static_argnames=("params", "interpret"))
 def dcqcn_update_tiled(state2d: tuple, ecn2d: jax.Array, line2d: jax.Array,
-                       t: jax.Array, params: tuple, interpret: bool = True):
+                       t: jax.Array, params: tuple,
+                       interpret: bool | None = None):
     """state2d: 8-tuple of (N8, 128) float32 arrays
     (rc, rt, alpha, t_cut, t_inc, t_alpha, inc_count, jit); returns the
-    7 updated state arrays (jit is static)."""
+    7 updated state arrays (jit is static).  The last block of 8 rows is
+    partial when 8 does not divide N8.  ``interpret=None`` resolves via
+    ``repro.kernels.default_interpret``."""
     pk = dict(params)
     N8 = ecn2d.shape[0]
     bs = min(8, N8)
@@ -71,10 +76,10 @@ def dcqcn_update_tiled(state2d: tuple, ecn2d: jax.Array, line2d: jax.Array,
     out_shape = [jax.ShapeDtypeStruct((N8, 128), jnp.float32)] * 7
     outs = pl.pallas_call(
         functools.partial(_kernel, **pk),
-        grid=(N8 // bs,),
+        grid=(pl.cdiv(N8, bs),),
         in_specs=[spec] * 10 + [tspec],
         out_specs=[spec] * 7,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(*state2d, ecn2d, line2d, t.reshape(1, 1))
     return outs

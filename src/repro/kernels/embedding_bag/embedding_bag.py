@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import default_interpret
+
 
 def _kernel(idx_ref, table_ref, out_ref):
     j = pl.program_id(1)
@@ -29,10 +31,11 @@ def _kernel(idx_ref, table_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def embedding_bag_rows(table2d: jax.Array, rows: jax.Array,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool | None = None) -> jax.Array:
     """table2d: (R, Dp) with Dp % 128 == 0; rows: (NB, P) int32.
 
-    Returns (NB, Dp) float32 sum-pooled bags."""
+    Returns (NB, Dp) float32 sum-pooled bags.  ``interpret=None``
+    resolves via ``repro.kernels.default_interpret``."""
     NB, P = rows.shape
     _, Dp = table2d.shape
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -47,5 +50,5 @@ def embedding_bag_rows(table2d: jax.Array, rows: jax.Array,
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((NB, Dp), jnp.float32),
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(rows, table2d)

@@ -8,12 +8,13 @@ from repro.kernels.embedding_bag.embedding_bag import embedding_bag_rows
 
 
 def embedding_bag_stacked(tables: jax.Array, idx: jax.Array,
-                          interpret: bool = True) -> jax.Array:
+                          interpret: bool | None = None) -> jax.Array:
     """tables (T, R, D), idx (B, T, P) int32 -> (B, T, D) in tables.dtype.
 
     Flattens the stacked tables to one (T*R, Dp) row space (row id =
     t*R + idx), pads D to a 128-lane multiple, and runs the
-    scalar-prefetch gather-accumulate kernel over (B*T, P)."""
+    scalar-prefetch gather-accumulate kernel over (B*T, P).
+    ``interpret=None`` resolves via ``repro.kernels.default_interpret``."""
     T, R, D = tables.shape
     B = idx.shape[0]
     P = idx.shape[2]

@@ -1,6 +1,4 @@
-# Fused engine-step kernels: signals+policy update and padded-gather
-# segment reduction.  ops.py (flat wrappers the engine dispatches to),
-# engine_step.py (tiled pallas_calls), ref.py (pure-jnp oracle).
-from repro.kernels.engine_step.ops import (fused_step,  # noqa: F401
-                                           segment_reduce,
-                                           segment_reduce_pfc)
+# Fused engine-step kernel: signals + policy update.  ops.py (the flat
+# wrapper the engine dispatches to), engine_step.py (the tiled
+# pallas_call), ref.py (pure-jnp oracle).
+from repro.kernels.engine_step.ops import fused_step  # noqa: F401

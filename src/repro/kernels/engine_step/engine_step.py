@@ -1,11 +1,9 @@
-"""Pallas TPU kernels for the fluid engine's hot inner loop.
-
-Two kernels cover the per-step work that dominates the simulator when
-sweeping CC x fabric x fault grids (see ``repro.core.engine`` stages 1-7):
+"""Pallas TPU kernel for the fluid engine's hot inner loop.
 
 ``fused_signals_policy_tiled``
-    Stages 1-2 fused into one VPU pass: ECN-mark product, queueing-delay
-    RTT and HPCC INT utilisation across the flow's MAXHOP path slots,
+    Engine stages 1-2 (see ``repro.core.engine``) fused into one VPU
+    pass: ECN-mark product, queueing-delay RTT and HPCC INT utilisation
+    across the flow's MAXHOP path slots,
     feeding directly into the *generic* per-flow policy state update — any
     kernel-eligible registered policy (all eight, the learned ``mlp``
     included: the ``Signals``-driven update is pure elementwise jnp, so
@@ -13,17 +11,13 @@ sweeping CC x fabric x fault grids (see ``repro.core.engine`` stages 1-7):
     ``kernels/cc_update``).  Flows tile
     (8, 128) (sublane x lane); the sweep batch axis is folded into the
     leading grid dimension, so a B-lane vmapped sweep is one grid of
-    B x N8/8 tiles instead of B separate dispatches.
+    B x ceil(N8/8) tiles instead of B separate dispatches.
 
-``segment_reduce_tiled`` / ``segment_reduce_pfc_tiled``
-    The engine's padded-gather segment reduction (``_reduce_plan``'s
-    "gather" strategy): ``out[s] = sum(vals[idx[s, :]])`` over a static
-    (n_out, C) index matrix, C <= 64 padded to one 128-lane row per
-    segment.  The ``_pfc`` variant fuses the PFC X_OFF/X_ON hysteresis on
-    the reduced per-port occupancy, collapsing engine stages 6-7 for the
-    pause signal into the same pass.
+The engine's segment reductions and PFC hysteresis stay XLA on every
+backend: their padded gather (``engine._reduce``) needs a 2-D vector
+index that Mosaic cannot lower.
 
-Params ride in SMEM as a packed (B, P) row per batch lane (sorted-key
+Params ride in SMEM as a packed (1, P) row per batch lane (sorted-key
 order from ``cc.kernel_param_keys``), so CC-parameter sweeps stay traced —
 no recompile per parameter point, matching the engine contract.
 """
@@ -79,7 +73,7 @@ def _signals_policy_kernel(qd_ref, txd_ref, caps_ref, emask_ref, hmask_ref,
     sig = cc_mod.Signals(ecn=ecn, rtt=rtt, util=util, t=t,
                          dt=jnp.float32(dt), line=line, base_rtt=base_rtt,
                          loss=loss)
-    params = {k: params_ref[0, j] for j, k in enumerate(param_keys)}
+    params = {k: params_ref[0, 0, j] for j, k in enumerate(param_keys)}
     state = {k: state_ref[0, j] for j, k in enumerate(state_keys)}
     st2, rate, win = update(params, state, sig)
     for j, k in enumerate(state_keys):
@@ -105,7 +99,9 @@ def fused_signals_policy_tiled(policy, hop_inputs: tuple, flat_inputs: tuple,
     128) packed in ``cc.kernel_state_keys`` order (K >= 1); ``params2d``:
     (B, P) packed in ``cc.kernel_param_keys`` order (P >= 1); ``t``:
     scalar sim time.  Returns (state', rate, win, ecn, rtt, util) with the
-    input shapes.  The batch axis B is the leading grid dimension.
+    input shapes.  The batch axis B is the leading grid dimension; the
+    flow axis is cut into blocks of 8 rows, the last one partial when 8
+    does not divide N8 (its out-of-range rows are never written).
     """
     state_keys = cc_mod.kernel_state_keys(policy)
     if state_keys is None:
@@ -120,7 +116,9 @@ def fused_signals_policy_tiled(policy, hop_inputs: tuple, flat_inputs: tuple,
     hop_spec = pl.BlockSpec((1, H, bs, 128), lambda b, i: (b, 0, i, 0))
     flat_spec = pl.BlockSpec((1, bs, 128), lambda b, i: (b, i, 0))
     st_spec = pl.BlockSpec((1, K, bs, 128), lambda b, i: (b, 0, i, 0))
-    p_spec = pl.BlockSpec((1, P), lambda b, i: (b, 0),
+    # one (1, P) params row per lane: the block's last two dims equal the
+    # array's, which Mosaic requires of an SMEM block at any B
+    p_spec = pl.BlockSpec((1, 1, P), lambda b, i: (b, 0, 0),
                           memory_space=pltpu.SMEM)
     t_spec = pl.BlockSpec((1, 1), lambda b, i: (0, 0),
                           memory_space=pltpu.SMEM)
@@ -132,83 +130,11 @@ def fused_signals_policy_tiled(policy, hop_inputs: tuple, flat_inputs: tuple,
         t_base_util=float(t_base_util), maxhop=H)
     return pl.pallas_call(
         kernel,
-        grid=(B, N8 // bs),
+        grid=(B, pl.cdiv(N8, bs)),
         in_specs=[hop_spec] * 8 + [flat_spec] * 3 + [st_spec, p_spec,
                                                      t_spec],
         out_specs=[st_spec] + [flat_spec] * 5,
         out_shape=out_shape,
         interpret=interpret,
-    )(*hop_inputs, *flat_inputs, state4d, params2d,
+    )(*hop_inputs, *flat_inputs, state4d, params2d.reshape(B, 1, P),
       jnp.asarray(t, jnp.float32).reshape(1, 1))
-
-
-# ---------------------------------------------------------------------------
-# kernel B: padded-gather segment reduction (+ fused PFC hysteresis)
-# ---------------------------------------------------------------------------
-
-def _seg_kernel(vals_ref, idx_ref, o_ref):
-    v = vals_ref[...]                            # (V8, 128) whole array
-    idx = idx_ref[...]                           # (bs, 128) int32
-    rows = v[idx // 128, idx % 128]              # gather; OOB -> zero pad
-    s = jnp.sum(rows, axis=1, keepdims=True)
-    o_ref[...] = jnp.broadcast_to(s, idx.shape)
-
-
-def _seg_pfc_kernel(vals_ref, idx_ref, xoff_ref, xon_ref, can_ref, prev_ref,
-                    o_q, o_paused):
-    v = vals_ref[...]
-    idx = idx_ref[...]
-    rows = v[idx // 128, idx % 128]
-    q = jnp.broadcast_to(jnp.sum(rows, axis=1, keepdims=True), idx.shape)
-    over = (q > xoff_ref[...]) & (can_ref[...] > 0)
-    under = q < xon_ref[...]
-    paused = jnp.where(over, 1.0,
-                       jnp.where(under, 0.0, prev_ref[...]))
-    o_q[...] = q
-    o_paused[...] = paused
-
-
-def segment_reduce_tiled(vals2d: jax.Array, idx2d: jax.Array, *,
-                         interpret: bool) -> jax.Array:
-    """``out[r] = sum(vals2d.flat[idx2d[r, :]])`` per padded segment row.
-
-    ``vals2d``: (V8, 128) float32 with zero slots appended past the live
-    values (every out-of-bounds index in ``idx2d`` points there);
-    ``idx2d``: (R, 128) int32, one 128-lane row per output segment.
-    Returns (R, 128) with the row sum broadcast across lanes.
-    """
-    V8 = vals2d.shape[0]
-    R = idx2d.shape[0]
-    bs = min(8, R)
-    vspec = pl.BlockSpec((V8, 128), lambda r: (0, 0))
-    ispec = pl.BlockSpec((bs, 128), lambda r: (r, 0))
-    return pl.pallas_call(
-        _seg_kernel,
-        grid=(R // bs,),
-        in_specs=[vspec, ispec],
-        out_specs=ispec,
-        out_shape=jax.ShapeDtypeStruct((R, 128), jnp.float32),
-        interpret=interpret,
-    )(vals2d, idx2d)
-
-
-def segment_reduce_pfc_tiled(vals2d, idx2d, xoff2d, xon2d, can2d, prev2d, *,
-                             interpret: bool):
-    """``segment_reduce_tiled`` with the PFC X_OFF/X_ON hysteresis fused:
-    per segment (= per ingress port) ``paused' = over ? 1 : under ? 0 :
-    prev`` where over keys on ``xoff``/``can`` and under on ``xon``.  The
-    per-port scalars arrive lane-broadcast as (R, 128).  Returns
-    ``(q, paused)``, both (R, 128)."""
-    V8 = vals2d.shape[0]
-    R = idx2d.shape[0]
-    bs = min(8, R)
-    vspec = pl.BlockSpec((V8, 128), lambda r: (0, 0))
-    ispec = pl.BlockSpec((bs, 128), lambda r: (r, 0))
-    return pl.pallas_call(
-        _seg_pfc_kernel,
-        grid=(R // bs,),
-        in_specs=[vspec] + [ispec] * 5,
-        out_specs=[ispec, ispec],
-        out_shape=[jax.ShapeDtypeStruct((R, 128), jnp.float32)] * 2,
-        interpret=interpret,
-    )(vals2d, idx2d, xoff2d, xon2d, can2d, prev2d)

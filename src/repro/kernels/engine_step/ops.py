@@ -1,13 +1,11 @@
-"""Wrappers: the engine's flat per-flow/per-link arrays -> tiled Pallas
-engine-step kernels -> flat.
+"""Wrapper: the engine's flat per-flow arrays -> tiled Pallas engine-step
+kernel -> flat.
 
 ``fused_step`` is the entry point ``repro.core.engine`` dispatches to when
 ``step_impl`` resolves to "pallas" (see ``engine.resolve_step_impl``):
-it pads the (F, MAXHOP) hop arrays and (F,) flow arrays to (8, 128) tiles,
+it pads the (F, MAXHOP) hop arrays and (F,) flow arrays to 128-lane rows,
 packs the policy state/params via the ``cc`` flat-array tables, runs the
-fused signals+policy kernel and unpacks.  ``segment_reduce`` /
-``segment_reduce_pfc`` wrap the padded-gather reduction the same way for
-``engine._reduce``'s "gather" strategy.
+fused signals+policy kernel and unpacks.
 
 Padding is inert by construction: padded lanes get neutral values (caps 1,
 kmax > kmin, masks 0) so no NaN/Inf can leak out of discarded lanes, and
@@ -19,9 +17,7 @@ import jax.numpy as jnp
 
 from repro.core import cc as cc_mod
 from repro.kernels import default_interpret
-from repro.kernels.engine_step.engine_step import (
-    fused_signals_policy_tiled, segment_reduce_pfc_tiled,
-    segment_reduce_tiled)
+from repro.kernels.engine_step.engine_step import fused_signals_policy_tiled
 
 
 def _tile_flat(x, n_pad, fill=0.0):
@@ -80,55 +76,3 @@ def fused_step(policy, *, q_d, tx_d, caps, ecn_mask, hopmask,
     return (new_state,
             rate[0].reshape(Fp)[:F],
             win[0].reshape(Fp)[:F])
-
-
-def _pack_seg(vals, idx, n_out: int, C: int):
-    """Pad gather operands to kernel tiles: vals to a (V8, 128) grid with
-    a zero tail (every OOB index clamps there), the flat (n_out*C,) index
-    matrix to one 128-lane row per segment, rows padded to a multiple of
-    8."""
-    n_in = vals.shape[0]
-    v_pad = (-(n_in + 1)) % 128 + 1              # >= 1 zero slot
-    vals2d = jnp.pad(vals.astype(jnp.float32), (0, v_pad)).reshape(-1, 128)
-    idx2d = idx.reshape(n_out, C)
-    idx2d = jnp.pad(idx2d, ((0, (-n_out) % 8), (0, 128 - C)),
-                    constant_values=n_in)
-    idx2d = jnp.minimum(idx2d, n_in).astype(jnp.int32)
-    return vals2d, idx2d
-
-
-def segment_reduce(vals, idx, n_out: int, C: int,
-                   interpret: bool | None = None):
-    """The "gather" strategy of ``engine._reduce_plan``: ``out[s] =
-    sum(vals[idx[s*C:(s+1)*C]])`` with OOB fill 0, as a Pallas row-sum.
-    ``idx`` is the plan's flat (n_out*C,) int32 matrix."""
-    interpret = default_interpret(interpret)
-    vals2d, idx2d = _pack_seg(vals, idx, n_out, C)
-    out = segment_reduce_tiled(vals2d, idx2d, interpret=interpret)
-    return out[:n_out, 0]
-
-
-def _lane_bcast(x, rows: int, fill=0.0):
-    """(n_out,) per-segment scalar -> (rows, 128) lane-broadcast tile."""
-    x = jnp.pad(x.astype(jnp.float32), (0, rows - x.shape[0]),
-                constant_values=fill)
-    return jnp.broadcast_to(x[:, None], (rows, 128))
-
-
-def segment_reduce_pfc(vals, idx, n_out: int, C: int, xoff, xon, can_pause,
-                       prev_paused, interpret: bool | None = None):
-    """Fused per-port occupancy reduction + PFC hysteresis (engine stages
-    6-7 for the pause signal): returns ``(q_port, paused)`` with ``paused``
-    boolean, matching the jnp path's ``where(over, True, where(under,
-    False, prev))``."""
-    interpret = default_interpret(interpret)
-    vals2d, idx2d = _pack_seg(vals, idx, n_out, C)
-    rows = idx2d.shape[0]
-    q, paused = segment_reduce_pfc_tiled(
-        vals2d, idx2d,
-        _lane_bcast(xoff, rows, fill=jnp.inf),
-        _lane_bcast(xon, rows),
-        _lane_bcast(can_pause.astype(jnp.float32), rows),
-        _lane_bcast(prev_paused.astype(jnp.float32), rows),
-        interpret=interpret)
-    return q[:n_out, 0], paused[:n_out, 0] > 0.5

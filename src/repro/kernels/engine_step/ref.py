@@ -1,8 +1,8 @@
-"""Oracle: engine stages 1-2 (and the gather reduction) in pure jnp.
+"""Oracle: engine stages 1-2 in pure jnp.
 
-Mirrors ``repro.core.engine._make_step``'s signal formulas and
-``_reduce``'s "gather" strategy exactly, so the kernel allclose tests pin
-the fused Pallas path to the engine's jnp semantics.
+Mirrors ``repro.core.engine._make_step``'s signal formulas exactly, so the
+kernel allclose tests pin the fused Pallas path to the engine's jnp
+semantics.
 """
 from __future__ import annotations
 
@@ -32,19 +32,3 @@ def fused_step_ref(policy, *, q_d, tx_d, caps, ecn_mask, hopmask,
                                    state, sig)
     F = line.shape[0]
     return (st2, jnp.broadcast_to(rate, (F,)), jnp.broadcast_to(win, (F,)))
-
-
-def segment_reduce_ref(vals, idx, n_out: int, C: int):
-    """``engine._reduce``'s "gather" strategy verbatim."""
-    rows = vals.at[idx].get(mode="fill", fill_value=0.0)
-    return rows.reshape(n_out, C).sum(axis=1)
-
-
-def segment_reduce_pfc_ref(vals, idx, n_out: int, C: int, xoff, xon,
-                           can_pause, prev_paused):
-    """Gather reduction + the engine's PFC hysteresis (stages 6-7)."""
-    q = segment_reduce_ref(vals, idx, n_out, C)
-    over = (q > xoff) & can_pause
-    under = q < xon
-    paused = jnp.where(over, True, jnp.where(under, False, prev_paused))
-    return q, paused

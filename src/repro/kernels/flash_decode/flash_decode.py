@@ -15,6 +15,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import default_interpret
+
 NEG_INF = -1e30
 
 
@@ -58,10 +60,12 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, length: jax.Array,
-                 block_s: int = 256, interpret: bool = True) -> jax.Array:
+                 block_s: int = 256,
+                 interpret: bool | None = None) -> jax.Array:
     """q: (B, Hkv, G, D); k/v: (B, S, Hkv, D); length: (B,) int32.
 
-    Returns (B, Hkv, G, D) attention output in q.dtype."""
+    Returns (B, Hkv, G, D) attention output in q.dtype.
+    ``interpret=None`` resolves via ``repro.kernels.default_interpret``."""
     B, Hkv, G, D = q.shape
     S = k.shape[1]
     assert S % block_s == 0, (S, block_s)
@@ -86,5 +90,5 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, length: jax.Array,
         functools.partial(_kernel, block_s=block_s, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(length.astype(jnp.int32), q, k, v)

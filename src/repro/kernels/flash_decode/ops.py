@@ -9,7 +9,7 @@ from repro.kernels.flash_decode.flash_decode import flash_decode
 
 def gqa_decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                          length: jax.Array, block_s: int = 256,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool | None = None) -> jax.Array:
     """q: (B, 1, Hq, D) over cache (B, S, Hkv, D); length () or (B,).
 
     Drop-in for models.layers.decode_attention on TPU."""

@@ -1,6 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST be the first lines: jax locks the device count on first init.
+# A CPU-only tool: pinned (and its --all children, which inherit this
+# environment) to the host, so it never takes an attached accelerator.
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 

@@ -26,7 +26,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.common.pytree import ParamDef
@@ -203,27 +202,27 @@ def moe_apply(p, x2d, cfg, mesh=None):
     elif impl == "tp":
         n_model = mesh.shape["model"]
         batch_axes = tuple(a for a in mesh.axis_names if a in ("pod", "data"))
-        fn = shard_map(
+        fn = jax.shard_map(
             partial(_moe_tp_local, cfg=cfg, n_model=n_model, model_axis="model"),
             mesh=mesh,
             in_specs=(P(None, None), P("model", None, None), P("model", None, None),
                       P("model", None, None), P(batch_axes, None)),
             out_specs=P(batch_axes, None),
-            check_rep=False,
+            check_vma=False,
         )
         routed = _moe_chunked(
             lambda xs: fn(p["router"], p["w1"], p["w3"], p["w2"], xs), x2d, cfg, mesh)
     elif impl == "ep_a2a":
         n_data = mesh.shape["data"]
         batch_axes = tuple(a for a in mesh.axis_names if a in ("pod", "data"))
-        fn = shard_map(
+        fn = jax.shard_map(
             partial(_moe_ep_local, cfg=cfg, n_data=n_data, data_axis="data",
                     model_axis="model"),
             mesh=mesh,
             in_specs=(P(None, None), P("data", None, "model"), P("data", None, "model"),
                       P("data", "model", None), P(batch_axes, None)),
             out_specs=P(batch_axes, None),
-            check_rep=False,
+            check_vma=False,
         )
         routed = _moe_chunked(
             lambda xs: fn(p["router"], p["w1"], p["w3"], p["w2"], xs), x2d, cfg, mesh)

@@ -19,7 +19,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -62,6 +61,6 @@ def gpipe(fn, stage_params, x, mesh, stage_axis: str = "stage"):
         return outs
 
     pspec = jax.tree.map(lambda _: P(stage_axis), stage_params)
-    return shard_map(local, mesh=mesh,
-                     in_specs=(pspec, P()), out_specs=P(),
-                     check_rep=False)(stage_params, x)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(pspec, P()), out_specs=P(),
+                         check_vma=False)(stage_params, x)

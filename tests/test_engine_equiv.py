@@ -34,7 +34,10 @@ def test_matches_seed_engine(tag, topo, sched, pol, cfg):
     np.testing.assert_allclose(r.completion_time, g["completion_time"],
                                rtol=1e-5)
     t_gold = np.array([np.inf if v is None else v for v in g["t_finish"]])
-    np.testing.assert_allclose(r.t_finish, t_gold, rtol=1e-5)
+    # completion is quantized to steps, like the PAUSE counts below: a
+    # rounding change (another XLA version) can flip the step in which a
+    # flow's last bytes clear eps_done, so each t_finish may move by one dt
+    np.testing.assert_allclose(r.t_finish, t_gold, rtol=1e-5, atol=cfg.dt)
     # delivered carries the eps_done completion slack (512 B/flow), so the
     # aggregate gets a correspondingly looser tolerance than t_finish
     np.testing.assert_allclose(r.delivered.sum(), g["delivered_sum"],

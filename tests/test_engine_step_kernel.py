@@ -6,14 +6,14 @@ Runs the Pallas kernels in interpret mode (the CI configuration on CPU;
 
 * ``fused_step`` (stages 1-2: signals + policy update) must be allclose
   (rtol 1e-5) to the reference for EVERY kernel-eligible registered
-  policy, lossless and lossy;
-* the padded-gather segment reduction (+ fused PFC hysteresis) must match
-  ``engine._reduce``'s "gather" strategy exactly;
+  policy, lossless and lossy, and for every flow when the flow count ends
+  in a partial block of tiles;
 * a full engine run with ``step_impl="pallas"`` must be allclose to
   ``step_impl="jnp"``;
 * the default path (``step_impl="auto"`` -> "jnp" off-accelerator) must
   stay bitwise on the PR-2 goldens (it shares the executable with an
-  explicit ``step_impl="jnp"`` by construction — asserted here);
+  explicit ``step_impl="jnp"`` by construction — asserted here), and
+  ``effective_step_impl`` must report the step each policy really runs;
 * ``SweepRunner`` batching decisions must follow the measured crossover
   table once ``calibrate_backend`` has cached one.
 """
@@ -27,7 +27,9 @@ import numpy as np
 import pytest
 
 from repro.core import cc, sweep
-from repro.core.engine import EngineConfig, _cfg_static, resolve_step_impl, simulate
+from repro.core.engine import (EngineConfig, _cfg_static,
+                               effective_step_impl, resolve_step_impl,
+                               simulate)
 from repro.kernels.engine_step import ops as es_ops
 from repro.kernels.engine_step import ref as es_ref
 
@@ -102,6 +104,28 @@ def test_fused_step_matches_ref(pol, lossy):
                                    rtol=1e-5, err_msg=f"state[{k!r}]")
 
 
+@pytest.mark.parametrize("n_flows", [1100, 7936])
+@pytest.mark.parametrize("pol", ["dcqcn", "hpcc"])
+def test_fused_step_partial_last_block(pol, n_flows):
+    """More than 8 rows of 128 flows, not a multiple of 8: the kernel's
+    last block is partial and its flows must still be computed (7936 is
+    the clos32 headline width)."""
+    policy = cc.get_policy(pol)
+    rng = np.random.default_rng(n_flows)
+    case = _rand_case(rng, n_flows=n_flows)
+    state, _ = _rand_state(policy, rng, n_flows=n_flows)
+    st_k, rate_k, win_k = es_ops.fused_step(
+        policy, state=state, params=None, interpret=True, **case)
+    st_r, rate_r, win_r = es_ref.fused_step_ref(
+        policy, state=state, params=None, **case)
+    np.testing.assert_allclose(rate_k, rate_r, rtol=1e-5)
+    np.testing.assert_allclose(win_k, win_r, rtol=1e-5)
+    for k in st_r:
+        np.testing.assert_allclose(
+            st_k[k], np.broadcast_to(st_r[k], (n_flows,)), rtol=1e-5,
+            err_msg=f"state[{k!r}]")
+
+
 def test_fused_step_param_overrides_ride_smem():
     """Non-default CC params must reach the kernel (packed SMEM row)."""
     policy = cc.get_policy("dcqcn")
@@ -164,38 +188,6 @@ def test_batched_tiles_match_per_lane():
                 err_msg=f"lane {b} state[{k!r}]")
 
 
-def test_segment_reduce_matches_gather():
-    """Padded-gather kernel == engine._reduce's gather strategy (exact)."""
-    rng = np.random.default_rng(3)
-    n_in, n_out, C = 777, 21, 37
-    vals = jnp.asarray(rng.uniform(0, 1e6, n_in), jnp.float32)
-    idx = rng.integers(0, n_in + 50, n_out * C)       # some OOB -> 0 fill
-    idx = jnp.asarray(np.minimum(idx, n_in), jnp.int32)
-    got = es_ops.segment_reduce(vals, idx, n_out, C, interpret=True)
-    want = es_ref.segment_reduce_ref(vals, idx, n_out, C)
-    # kernel sums the full padded 128-lane row (zeros in the tail), so
-    # association order can differ from the (n_out, C) reshape by an ULP
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-6)
-
-
-def test_segment_reduce_pfc_matches_ref():
-    rng = np.random.default_rng(5)
-    n_in, n_out, C = 512, 17, 31
-    vals = jnp.asarray(rng.uniform(0, 2e6, n_in), jnp.float32)
-    idx = jnp.asarray(rng.integers(0, n_in, n_out * C), jnp.int32)
-    xoff = jnp.asarray(rng.uniform(5e6, 20e6, n_out), jnp.float32)
-    xon = xoff * 0.8
-    can = jnp.asarray(rng.random(n_out) < 0.5)
-    prev = jnp.asarray(rng.random(n_out) < 0.5)
-    q_k, p_k = es_ops.segment_reduce_pfc(vals, idx, n_out, C, xoff, xon,
-                                         can, prev, interpret=True)
-    q_r, p_r = es_ref.segment_reduce_pfc_ref(vals, idx, n_out, C, xoff,
-                                             xon, can, prev)
-    np.testing.assert_allclose(np.asarray(q_k), np.asarray(q_r), rtol=1e-6)
-    np.testing.assert_array_equal(np.asarray(p_k), np.asarray(p_r))
-
-
 # -- engine dispatch ---------------------------------------------------------
 
 def _scenario():
@@ -231,8 +223,7 @@ def test_default_impl_is_jnp_off_accelerator_and_bitwise_golden():
     default path reproduces the PR-2 goldens bitwise; one golden scenario
     is re-checked here under an explicit ``step_impl="jnp"``."""
     cfg = EngineConfig()
-    expect = "jnp" if jax.default_backend() not in ("tpu", "gpu") \
-        else "pallas"
+    expect = "pallas" if jax.default_backend() == "tpu" else "jnp"
     assert resolve_step_impl(cfg) == expect
     assert _cfg_static(cfg) == _cfg_static(
         dataclasses.replace(cfg, step_impl=resolve_step_impl(cfg)))
@@ -246,6 +237,17 @@ def test_default_impl_is_jnp_off_accelerator_and_bitwise_golden():
                  dataclasses.replace(cfg, step_impl="jnp"))
     np.testing.assert_allclose(r.completion_time, g["completion_time"],
                                rtol=1e-5)
+
+
+def test_effective_step_impl_reports_the_step_that_runs():
+    """A kernel-eligible policy runs the kernel under "pallas"; a stacked
+    product policy runs the jnp step whatever the config asks."""
+    pallas = EngineConfig(step_impl="pallas")
+    assert effective_step_impl(cc.get_policy("dcqcn"), pallas) == "pallas"
+    assert effective_step_impl(cc.get_policy("dcqcn"),
+                               EngineConfig(step_impl="jnp")) == "jnp"
+    stacked = cc.stack_policies([cc.get_policy(p) for p in ALL])
+    assert effective_step_impl(stacked, pallas) == "jnp"
 
 
 def test_resolve_step_impl_rejects_unknown():

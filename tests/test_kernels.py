@@ -75,7 +75,9 @@ def test_flash_decode_property(B, S, cut):
 
 
 # ---------------------------------------------------------------- cc update
-@pytest.mark.parametrize("F", [7, 128, 300, 1000])
+# 1100 and 7936 flows: more than 8 rows of 128, not a multiple of 8, so
+# the kernel's last block is partial
+@pytest.mark.parametrize("F", [7, 128, 300, 1000, 1100, 7936])
 def test_cc_update_matches_policy(F, key):
     pol = make_dcqcn()
     line = jnp.full((F,), 25e9, jnp.float32)

@@ -8,14 +8,18 @@ single-device-safe and always run.
 """
 import dataclasses
 import json
+import os
 import warnings
 
 import jax
 import numpy as np
 import pytest
 
+from repro.common import cache as cache_mod
+from repro.common.cache import backend_compiles
 from repro.common.sharding import GRID_AXIS, grid_mesh, resolve_grid_mesh
 from repro.core import sweep as sweep_mod
+from repro.core.cc import get_policy
 from repro.core.collectives import allreduce_1d, incast
 from repro.core.engine import EngineConfig
 from repro.core.faults import FaultSpec
@@ -101,6 +105,27 @@ def test_unsharded_chunked_streaming_matches_single_dispatch():
                                stacked["rai_frac"])
 
 
+def test_compile_batch_is_the_executable_run_batch_dispatches():
+    """After ``compile_batch`` (and ``Simulator.compile``) the matching
+    run compiles nothing, and the results equal an uncompiled runner's."""
+    topo, sched = scenario(n=5)
+    stacked = {"rai_frac": np.asarray([0.02, 0.03, 0.05], np.float32)}
+    runner = SweepRunner(CFG)
+    compiled = runner.compile_batch(topo, sched, "dcqcn", stacked)
+    assert "while" in compiled.as_text()
+    with backend_compiles() as got:
+        batch = runner.run_batch(topo, sched, "dcqcn", stacked)
+    assert got == []
+    want = SweepRunner(CFG).run_batch(topo, sched, "dcqcn", stacked)
+    np.testing.assert_array_equal(batch.t_finish, want.t_finish)
+
+    sim = runner.simulator(topo, sched, get_policy("hpcc"))
+    sim.compile()
+    with backend_compiles() as got:
+        r = runner.run(topo, sched, "hpcc")
+    assert got == [] and r.finished
+
+
 def test_lane_state_bytes_positive_and_faulty_larger():
     topo, sched = scenario()
     r = SweepRunner(CFG)
@@ -152,7 +177,7 @@ def test_get_calibration_warm_starts_from_disk(tmp_path, monkeypatch):
     """A fresh process (simulated: cleared in-memory table + _NO_DISK)
     picks up the persisted measurement; reset_calibration pins back to
     the defaults without reconsulting the file."""
-    monkeypatch.setenv("REPRO_CACHE_DIR", str(tmp_path))
+    monkeypatch.setattr(cache_mod, "CACHE_ROOT", str(tmp_path))
     backend = jax.default_backend()
     cal = BackendCalibration(backend=backend, source="measured",
                              crossover={"sweep": 777.0})
@@ -174,8 +199,32 @@ def test_get_calibration_warm_starts_from_disk(tmp_path, monkeypatch):
         sweep_mod._NO_DISK.update(saved_nodisk)
 
 
+def test_cache_paths_are_fixed_whatever_the_cwd(tmp_path, monkeypatch):
+    """The compilation cache and calibrations sit under
+    ``<checkout>/.cache`` from any cwd; with ``JAX_COMPILATION_CACHE_DIR``
+    set, JAX keeps its own directory and no other is configured."""
+    calls = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: calls.__setitem__(k, v))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("REPRO_COMPILATION_CACHE", raising=False)
+    monkeypatch.chdir(tmp_path)
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), ".cache")
+    want = os.path.join(root, "jax_compilation")
+    assert cache_mod.enable_compilation_cache() == want
+    assert calls["jax_compilation_cache_dir"] == want
+    assert sweep_mod.calibration_cache_path("cpu") == os.path.join(
+        root, "repro_calibration_cpu.json")
+    calls.clear()
+    own = str(tmp_path / "jax_own")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", own)
+    assert cache_mod.enable_compilation_cache() == own
+    assert "jax_compilation_cache_dir" not in calls
+
+
 def test_get_calibration_env_gate(tmp_path, monkeypatch):
-    monkeypatch.setenv("REPRO_CACHE_DIR", str(tmp_path))
+    monkeypatch.setattr(cache_mod, "CACHE_ROOT", str(tmp_path))
     monkeypatch.setenv("REPRO_CALIBRATION_CACHE", "0")
     backend = jax.default_backend()
     sweep_mod.save_calibration(BackendCalibration(

@@ -11,15 +11,8 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.common.sharding import MeshRules
 
-def _abstract_mesh(sizes, names):
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:   # jax<=0.4.x signature: AbstractMesh(((name, size), ...))
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
-MESH = _abstract_mesh((16, 16), ("data", "model"))
-MESH3 = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_basic_rules():
