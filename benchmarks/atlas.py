@@ -226,9 +226,7 @@ def main(argv=None):
         },
         "note": "emulated host devices share one core: the sharded "
                 "dispatch here validates placement/equivalence at paper "
-                "scale, wall-clock parallel speedup needs real devices "
-                "(BENCH_engine.json 'sharded' records measured "
-                "efficiency)",
+                "scale, wall-clock parallel speedup needs real devices",
     }
     with open(os.path.join(OUTDIR, f"atlas_{tag}.json"), "w") as f:
         json.dump(side, f, indent=1)

@@ -72,79 +72,6 @@ def analyze(rec: dict) -> dict:
     }
 
 
-# -- engine-step roofline ---------------------------------------------------
-# Analytic bytes/FLOP model of one fluid-engine step (repro.core.engine
-# ``_make_step``), sized from the scenario's flow count.  Used two ways:
-# ``benchmarks/run.py`` always emits these rows (no dry-run artifacts
-# needed) and ``benchmarks/bench_engine.py`` records them next to the
-# measured step timings in BENCH_engine.json.
-
-MAXHOP = 4          # engine.MAXHOP: padded hop slots per flow
-F32 = 4             # bytes per element, everything in the step is f32
-
-
-def engine_step_roofline(n_flows: int, maxhop: int = MAXHOP,
-                         n_state: int = 8, n_links: int = 64,
-                         fanin: int = 64) -> dict:
-    """Memory-traffic and FLOP estimate for one engine step at ``n_flows``.
-
-    Two traffic models: ``fused`` counts each operand once per kernel
-    (the ``step_impl="pallas"`` packing — repro.kernels.engine_step reads
-    the 8 hop-shaped inputs + 3 flow inputs + state and writes state +
-    rate/win/diagnostics in one pass); ``jnp`` adds the materialized
-    intermediates the op-by-op path streams through memory (each hop-
-    shaped temporary is written then re-read).  FLOPs are identical —
-    the fusion win is pure traffic, so arithmetic intensity rises by
-    the traffic ratio."""
-    F, H, K = float(n_flows), float(maxhop), float(n_state)
-    hop = F * H
-    # stages 1-2: signals (mark/rtt/util over hops) + policy update
-    sig_reads = 8 * hop + 3 * F + K * F
-    sig_writes = K * F + 5 * F
-    # mark, unmarked-product, rtt/util partials: ~6 hop-shaped temporaries
-    # plus ~8 flow-shaped ones, each written and re-read by the next op
-    sig_intermediate = 2 * (6 * hop + 8 * F)
-    # stages 5-6: padded-gather segment reductions (per-hop demand x H,
-    # qlink, qport): vals + int32 index matrix + output per reduction
-    n_out = float(n_links)
-    gat = (H + 2) * (hop + 2 * n_out * fanin + n_out)
-    bytes_fused = F32 * (sig_reads + sig_writes + gat)
-    bytes_jnp = bytes_fused + F32 * sig_intermediate
-    # ~14 flops/lane for mark/rtt/util, ~45/flow for a DCQCN-class update,
-    # one add per gathered element
-    flops = 14 * hop + 45 * F + (H + 2) * n_out * fanin
-    ridge = PEAK_FLOPS / HBM_BW
-    out = {
-        "n_flows": int(n_flows),
-        "flops_per_step": flops,
-        "bytes_fused": bytes_fused,
-        "bytes_jnp": bytes_jnp,
-        "traffic_ratio": round(bytes_jnp / bytes_fused, 3),
-        "intensity_fused": round(flops / bytes_fused, 4),
-        "intensity_jnp": round(flops / bytes_jnp, 4),
-        "ridge_flop_per_byte": round(ridge, 1),
-        "memory_bound": flops / bytes_fused < ridge,
-        "est_step_us_fused_hbm": round(bytes_fused / HBM_BW * 1e6, 3),
-        "est_step_us_jnp_hbm": round(bytes_jnp / HBM_BW * 1e6, 3),
-    }
-    return out
-
-
-def engine_step_rows(sizes=(256, 7936, 65536)) -> list:
-    """CSV rows (figure, metric, policy, value) for ``benchmarks/run.py``:
-    the engine-step roofline at representative scenario sizes (8-GPU
-    autotune regime, the 32-GPU headline All-Reduce, a paper-scale
-    128-GPU All-to-All)."""
-    rows = []
-    for n in sizes:
-        r = engine_step_roofline(n)
-        tag = f"roofline_engine_step_{n}"
-        for k in ("traffic_ratio", "intensity_fused", "intensity_jnp",
-                  "est_step_us_fused_hbm", "memory_bound"):
-            rows.append((tag, k, "-", r[k]))
-    return rows
-
-
 def main(dryrun_dir: str = "experiments/dryrun"):
     rows = []
     for path in sorted(glob.glob(os.path.join(dryrun_dir, "*.json"))):

@@ -37,11 +37,8 @@ def main() -> int:
             failed.append(fn.__name__)
         emit([(fn.__name__, "wall_s", "-", round(time.time() - t0, 1))])
 
-    # engine-step roofline: analytic, always available
-    from benchmarks import roofline
-    emit(roofline.engine_step_rows())
-
     # model roofline (reads dry-run artifacts if present)
+    from benchmarks import roofline
     if os.path.isdir("experiments/dryrun") and os.listdir("experiments/dryrun"):
         print("--- roofline (from dry-run artifacts) ---")
         roofline.main()

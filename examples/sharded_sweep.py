@@ -4,8 +4,8 @@ and through a device mesh, equivalence-checked, plus streamed chunking.
 On a machine with one device this demo emulates 8 before importing jax
 (the `XLA_FLAGS=--xla_force_host_platform_device_count=8` testing recipe
 from README "Scaling sweeps across devices").  Emulated devices share the
-host's cores — the point here is placement and equivalence, not speed;
-see BENCH_engine.json "sharded" for honest scaling numbers.
+host's cores — the point here is placement and equivalence, not speed,
+which only real chips can show (``bench/run.py``, ``PERF.md``).
 
 Run:  PYTHONPATH=src python examples/sharded_sweep.py
 """

@@ -74,9 +74,10 @@ JOURNAL_DIR = "journal"
 MANIFEST = "manifest.json"
 FINGERPRINT = "fingerprint.json"
 
-# the per-lane result arrays a chunk journals (exactly the array fields
-# of BatchResults; params/fabric/fault are re-derived from the task spec
-# at merge time, so the journal stays compact)
+# the per-lane result arrays a chunk journals (the array fields of
+# BatchResults but steps_run, which belongs to one dispatch, not to the
+# lane; params/fabric/fault are re-derived from the task spec at merge
+# time, so the journal stays compact)
 RESULT_KEYS = ("completion_time", "t_finish", "pause_count", "delivered",
                "soft_cost", "finished", "diverged", "deadlock_step",
                "storm_step", "extend_exhausted")

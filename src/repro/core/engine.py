@@ -610,6 +610,13 @@ def _make_step(policy: Policy, cfg: EngineConfig, plan: _Plan,
         from repro.kernels.engine_step import ops as es_ops
 
     def step(carry, it, pp, cc_params, flt, tab):
+        with jax.named_scope("engine_step"):
+            return _step(carry, it, pp, cc_params, flt, tab)
+
+    def _step(carry, it, pp, cc_params, flt, tab):
+        # each stage runs under a named scope of its own, which the
+        # benchmark reads from the executable's op metadata
+        # (bench/stages.py ``op_stages``): the names are its yardstick
         def _pause_cycle(paused):
             """Any cycle in the switch->switch PFC wait-for graph?  Link l
             paused means src_dev(l) waits on dst_dev(l) to resume."""
@@ -629,225 +636,250 @@ def _make_step(policy: Policy, cfg: EngineConfig, plan: _Plan,
         t_end = (it + 1).astype(jnp.float32) * dt
         kmin_h, kmax_h, pmax_h = tab["kmin_h"], tab["kmax_h"], tab["pmax_h"]
         # ---- 1. delayed signals ------------------------------------------
-        idx = jnp.maximum(it - pp["delay_steps"], 0) % plan.ring
-        flat = idx[:, None] * (Lk + 1) + path            # (F, MAXHOP)
-        q_d = carry["hist_q"].reshape(-1)[flat]
-        tx_d = carry["hist_tx"].reshape(-1)[flat]
-        caps = pp["caps_path"]
+        with jax.named_scope("s1_signals"):
+            idx = jnp.maximum(it - pp["delay_steps"], 0) % plan.ring
+            flat = idx[:, None] * (Lk + 1) + path            # (F, MAXHOP)
+            q_d = carry["hist_q"].reshape(-1)[flat]
+            tx_d = carry["hist_tx"].reshape(-1)[flat]
+            caps = pp["caps_path"]
+            if not use_kernel:
+                rtt = pp["base_rtt"] + (q_d / caps * hopmask).sum(1)
+                mark = jnp.clip(
+                    (q_d - kmin_h) / jnp.maximum(kmax_h - kmin_h, 1.0),
+                    0.0, 1.0) * pmax_h
+                if faulty:
+                    # ECN misconfiguration: scale marking probability
+                    # (0 = broken)
+                    mark = mark * tab["ecn_scale_h"]
+                mark = mark * pp["ecn_mask"]
+                ecn = 1.0 - jnp.prod(1.0 - mark, axis=1)
+                util_l = tx_d / caps + q_d / (caps * cfg.t_base_util)
+                util = jnp.max(jnp.where(hopmask, util_l, 0.0), axis=1)
+                if faulty:
+                    sig = Signals(ecn=ecn, rtt=rtt, util=util, t=t,
+                                  dt=jnp.float32(dt), line=pp["line"],
+                                  base_rtt=pp["base_rtt"],
+                                  loss=carry["loss_sig"])
+                else:
+                    sig = Signals(ecn=ecn, rtt=rtt, util=util, t=t,
+                                  dt=jnp.float32(dt), line=pp["line"],
+                                  base_rtt=pp["base_rtt"])
         if use_kernel:
             # ---- 1+2 fused: signals + CC update in one Pallas pass ------
-            # ECN misconfiguration folds into the marking ceiling (same
-            # product as the jnp path's post-clip scale)
-            pmax_eff = pmax_h
-            if faulty:
-                pmax_eff = pmax_eff * tab["ecn_scale_h"]
-            loss = (carry["loss_sig"] if faulty
-                    else jnp.zeros_like(pp["line"]))
-            cc, rate, win = es_ops.fused_step(
-                policy, q_d=q_d, tx_d=tx_d, caps=caps,
-                ecn_mask=pp["ecn_mask"], hopmask=hopmask,
-                kmin_h=kmin_h, kmax_h=kmax_h, pmax_h=pmax_eff,
-                base_rtt=pp["base_rtt"], line=pp["line"], loss=loss,
-                state=carry["cc"], params=cc_params, t=t, dt=dt,
-                t_base_util=cfg.t_base_util)
+            with jax.named_scope("s12_fused"):
+                # ECN misconfiguration folds into the marking ceiling (same
+                # product as the jnp path's post-clip scale)
+                pmax_eff = pmax_h
+                if faulty:
+                    pmax_eff = pmax_eff * tab["ecn_scale_h"]
+                loss = (carry["loss_sig"] if faulty
+                        else jnp.zeros_like(pp["line"]))
+                cc, rate, win = es_ops.fused_step(
+                    policy, q_d=q_d, tx_d=tx_d, caps=caps,
+                    ecn_mask=pp["ecn_mask"], hopmask=hopmask,
+                    kmin_h=kmin_h, kmax_h=kmax_h, pmax_h=pmax_eff,
+                    base_rtt=pp["base_rtt"], line=pp["line"], loss=loss,
+                    state=carry["cc"], params=cc_params, t=t, dt=dt,
+                    t_base_util=cfg.t_base_util)
         else:
-            rtt = pp["base_rtt"] + (q_d / caps * hopmask).sum(1)
-            mark = jnp.clip((q_d - kmin_h) / jnp.maximum(kmax_h - kmin_h, 1.0),
-                            0.0, 1.0) * pmax_h
-            if faulty:
-                # ECN misconfiguration: scale marking probability (0 = broken)
-                mark = mark * tab["ecn_scale_h"]
-            mark = mark * pp["ecn_mask"]
-            ecn = 1.0 - jnp.prod(1.0 - mark, axis=1)
-            util_l = tx_d / caps + q_d / (caps * cfg.t_base_util)
-            util = jnp.max(jnp.where(hopmask, util_l, 0.0), axis=1)
-            if faulty:
-                sig = Signals(ecn=ecn, rtt=rtt, util=util, t=t,
-                              dt=jnp.float32(dt), line=pp["line"],
-                              base_rtt=pp["base_rtt"], loss=carry["loss_sig"])
-            else:
-                sig = Signals(ecn=ecn, rtt=rtt, util=util, t=t,
-                              dt=jnp.float32(dt), line=pp["line"],
-                              base_rtt=pp["base_rtt"])
-
             # ---- 2. CC update ---------------------------------------------
-            cc, rate, win = policy.update(cc_params, carry["cc"], sig)
+            with jax.named_scope("s2_cc"):
+                cc, rate, win = policy.update(cc_params, carry["cc"], sig)
 
         # ---- 3. injection --------------------------------------------------
-        dep = pp["dep"]
-        g_done = carry["g_count"] >= pp["gsize"] - 0.5
-        dep_ok = jnp.where(dep >= 0, g_done[jnp.maximum(dep, 0)], True)
-        dep_t = jnp.where(dep >= 0, carry["g_time"][jnp.maximum(dep, 0)], 0.0)
-        started = dep_ok & (t >= dep_t + pp["sdelay"])
-        inflight = carry["injected"] - carry["delivered"]
-        if faulty:
-            # lost bytes are not in flight (the NIC saw the NACK/timeout)
-            inflight = inflight - carry["lost"]
-        room = jnp.maximum(win - inflight, 0.0)
-        inj = jnp.minimum(jnp.minimum(rate * dt, room), carry["remaining"])
-        inj = jnp.where(started & (pp["n_hops"] > 0), jnp.maximum(inj, 0.0), 0.0)
-        backlog = carry["backlog"].at[:, 0].add(inj)
-        remaining = carry["remaining"] - inj
-        injected = carry["injected"] + inj
+        with jax.named_scope("s3_inject"):
+            dep = pp["dep"]
+            g_done = carry["g_count"] >= pp["gsize"] - 0.5
+            dep_ok = jnp.where(dep >= 0, g_done[jnp.maximum(dep, 0)], True)
+            dep_t = jnp.where(dep >= 0, carry["g_time"][jnp.maximum(dep, 0)],
+                              0.0)
+            started = dep_ok & (t >= dep_t + pp["sdelay"])
+            inflight = carry["injected"] - carry["delivered"]
+            if faulty:
+                # lost bytes are not in flight (the NIC saw the NACK/timeout)
+                inflight = inflight - carry["lost"]
+            room = jnp.maximum(win - inflight, 0.0)
+            inj = jnp.minimum(jnp.minimum(rate * dt, room), carry["remaining"])
+            inj = jnp.where(started & (pp["n_hops"] > 0), jnp.maximum(inj, 0.0),
+                            0.0)
+            backlog = carry["backlog"].at[:, 0].add(inj)
+            remaining = carry["remaining"] - inj
+            injected = carry["injected"] + inj
 
         # ---- 4. PFC gates (per-port) ---------------------------------------
-        gate = ~carry["paused"]
-        rem_cap = pp["cap"] * dt * gate
-        if faulty:
-            # time-scheduled capacity faults on fabric links: degradation
-            # windows and periodic link flaps (down for flap_down out of
-            # every flap_period seconds)
-            deg = tab["degrade_l"]
-            in_deg = (t >= flt.degrade_t0) & (t < flt.degrade_t1)
-            capmul = jnp.where(in_deg & (pp["fabric_link"] > 0), deg, 1.0)
-            period = jnp.asarray(flt.flap_period, jnp.float32)
-            phase = jnp.mod(t - flt.flap_t0, jnp.maximum(period, 1e-9))
-            flap_down = ((period > 0) & (t >= flt.flap_t0)
-                         & (phase < flt.flap_down))
-            capmul = jnp.where(flap_down & (pp["fabric_link"] > 0),
-                               0.0, capmul)
-            rem_cap = rem_cap * capmul
-        rem_cap = rem_cap.at[Lk].set(1e18)
+        with jax.named_scope("s4_gates"):
+            gate = ~carry["paused"]
+            rem_cap = pp["cap"] * dt * gate
+            if faulty:
+                # time-scheduled capacity faults on fabric links: degradation
+                # windows and periodic link flaps (down for flap_down out of
+                # every flap_period seconds)
+                deg = tab["degrade_l"]
+                in_deg = (t >= flt.degrade_t0) & (t < flt.degrade_t1)
+                capmul = jnp.where(in_deg & (pp["fabric_link"] > 0), deg, 1.0)
+                period = jnp.asarray(flt.flap_period, jnp.float32)
+                phase = jnp.mod(t - flt.flap_t0, jnp.maximum(period, 1e-9))
+                flap_down = ((period > 0) & (t >= flt.flap_t0)
+                             & (phase < flt.flap_down))
+                capmul = jnp.where(flap_down & (pp["fabric_link"] > 0),
+                                   0.0, capmul)
+                rem_cap = rem_cap * capmul
+            rem_cap = rem_cap.at[Lk].set(1e18)
 
         # ---- 5. hop-ordered forwarding -------------------------------------
-        delivered = carry["delivered"]
-        tx_bytes = jnp.zeros(Lk + 1, jnp.float32)
-        if faulty:
-            # per-hop drop probability: fabric links only (NVLink lossless)
-            loss_p = tab["loss_h"] * pp["fabric_path"]
-            lost_step = jnp.zeros_like(carry["lost"])
-        for h in range(MAXHOP):
-            if plan.hop[h][0] == "empty":   # no flow ever uses this hop slot
-                continue
-            dem = _reduce(plan.hop[h], pp["r_hop"][h], backlog[:, h])
-            frac = jnp.where(dem > 0,
-                             jnp.minimum(1.0, rem_cap / jnp.maximum(dem, 1e-9)),
-                             0.0)
-            moved = backlog[:, h] * frac[path[:, h]]
-            backlog = backlog.at[:, h].add(-moved)
+        with jax.named_scope("s5_forward"):
+            delivered = carry["delivered"]
+            tx_bytes = jnp.zeros(Lk + 1, jnp.float32)
             if faulty:
-                # bytes dropped on this hop consumed upstream capacity but
-                # leave the network; they re-enter `remaining` below
-                drop = moved * loss_p[:, h]
-                lost_step = lost_step + drop
-                moved = moved - drop
-            last = pp["n_hops"] == (h + 1)
-            delivered = delivered + jnp.where(last, moved, 0.0)
-            if h + 1 < MAXHOP:
-                backlog = backlog.at[:, h + 1].add(jnp.where(last, 0.0, moved))
-            movedsum = frac * dem          # == per-link sum of `moved`
-            rem_cap = jnp.maximum(rem_cap - movedsum, 0.0)
-            tx_bytes = tx_bytes + movedsum
+                # per-hop drop probability: fabric links only (NVLink
+                # lossless)
+                loss_p = tab["loss_h"] * pp["fabric_path"]
+                lost_step = jnp.zeros_like(carry["lost"])
+            for h in range(MAXHOP):
+                if plan.hop[h][0] == "empty":   # no flow ever uses this slot
+                    continue
+                dem = _reduce(plan.hop[h], pp["r_hop"][h], backlog[:, h])
+                frac = jnp.where(
+                    dem > 0, jnp.minimum(1.0, rem_cap / jnp.maximum(dem, 1e-9)),
+                    0.0)
+                moved = backlog[:, h] * frac[path[:, h]]
+                backlog = backlog.at[:, h].add(-moved)
+                if faulty:
+                    # bytes dropped on this hop consumed upstream capacity
+                    # but leave the network; they re-enter `remaining` below
+                    drop = moved * loss_p[:, h]
+                    lost_step = lost_step + drop
+                    moved = moved - drop
+                last = pp["n_hops"] == (h + 1)
+                delivered = delivered + jnp.where(last, moved, 0.0)
+                if h + 1 < MAXHOP:
+                    backlog = backlog.at[:, h + 1].add(
+                        jnp.where(last, 0.0, moved))
+                movedsum = frac * dem          # == per-link sum of `moved`
+                rem_cap = jnp.maximum(rem_cap - movedsum, 0.0)
+                tx_bytes = tx_bytes + movedsum
 
         if faulty:
             # ---- 5b. loss recovery (IRN vs go-back-N) ----------------------
-            lost = carry["lost"] + lost_step
-            live = jnp.maximum(injected - delivered - lost, 0.0)
-            gbn = jnp.asarray(flt.gbn, jnp.float32)
-            mtu = jnp.maximum(jnp.asarray(flt.mtu, jnp.float32), 1.0)
-            # IRN (selective retransmit): only the lost bytes are resent.
-            # go-back-N: each lost packet (lost_step/mtu of them) resends on
-            # average half the NIC's outstanding window too.  The window is
-            # the in-network bytes capped at the path BDP: fluid "live"
-            # includes queued backlog, which a real NIC's send window never
-            # covers — uncapped, incast GBN resends faster than the
-            # bottleneck drains and can never terminate
-            w_out = jnp.minimum(live, pp["line"] * pp["base_rtt"])
-            dup_step = gbn * jnp.minimum(lost_step * w_out / (2.0 * mtu),
-                                         live)
-            remaining = remaining + lost_step + dup_step
-            dup = carry["dup"] + dup_step
-            # per-flow EWMA loss fraction (the `loss` CC signal, read next
-            # step so it is RTT-delayed like the other signals)
-            a = jnp.minimum(dt / pp["base_rtt"], 1.0)
-            traf = lost_step + (delivered - carry["delivered"])
-            frac_l = lost_step / jnp.maximum(traf, 1.0)
-            loss_sig = jnp.where(traf > 0,
-                                 (1.0 - a) * carry["loss_sig"] + a * frac_l,
-                                 carry["loss_sig"])
+            with jax.named_scope("s5b_loss"):
+                lost = carry["lost"] + lost_step
+                live = jnp.maximum(injected - delivered - lost, 0.0)
+                gbn = jnp.asarray(flt.gbn, jnp.float32)
+                mtu = jnp.maximum(jnp.asarray(flt.mtu, jnp.float32), 1.0)
+                # IRN (selective retransmit): only the lost bytes are resent.
+                # go-back-N: each lost packet (lost_step/mtu of them) resends
+                # on average half the NIC's outstanding window too.  The
+                # window is the in-network bytes capped at the path BDP:
+                # fluid "live" includes queued backlog, which a real NIC's
+                # send window never covers — uncapped, incast GBN resends
+                # faster than the bottleneck drains and can never terminate
+                w_out = jnp.minimum(live, pp["line"] * pp["base_rtt"])
+                dup_step = gbn * jnp.minimum(lost_step * w_out / (2.0 * mtu),
+                                             live)
+                remaining = remaining + lost_step + dup_step
+                dup = carry["dup"] + dup_step
+                # per-flow EWMA loss fraction (the `loss` CC signal, read
+                # next step so it is RTT-delayed like the other signals)
+                a = jnp.minimum(dt / pp["base_rtt"], 1.0)
+                traf = lost_step + (delivered - carry["delivered"])
+                frac_l = lost_step / jnp.maximum(traf, 1.0)
+                loss_sig = jnp.where(
+                    traf > 0, (1.0 - a) * carry["loss_sig"] + a * frac_l,
+                    carry["loss_sig"])
 
         # ---- 6. queues ------------------------------------------------------
-        q_link = _reduce(plan.qlink, pp["r_qlink"], backlog.reshape(-1))
-        xoff_l, xon_l = tab["xoff_l"], tab["xon_l"]
-        can = pp["can_pause"]
-        if faulty:
-            # PFC misconfiguration / lossy-RoCE: pfc_on=0 disables pausing
-            can = can & (tab["pfc_on_l"] > 0.5)
-        # per-ingress-port occupancy at the receiving switch
-        q_port = _reduce(plan.qport, pp["r_qport"], backlog.reshape(-1))
+        with jax.named_scope("s6_queues"):
+            q_link = _reduce(plan.qlink, pp["r_qlink"], backlog.reshape(-1))
+            xoff_l, xon_l = tab["xoff_l"], tab["xon_l"]
+            can = pp["can_pause"]
+            if faulty:
+                # PFC misconfiguration / lossy-RoCE: pfc_on=0 disables pausing
+                can = can & (tab["pfc_on_l"] > 0.5)
+            # per-ingress-port occupancy at the receiving switch
+            q_port = _reduce(plan.qport, pp["r_qport"], backlog.reshape(-1))
 
         # ---- 7. PFC per-port hysteresis -------------------------------------
-        over = (q_port > xoff_l) & can
-        under = q_port < xon_l
-        paused = jnp.where(over, True,
-                           jnp.where(under, False, carry["paused"]))
-        # PAUSE frames: one on the off-transition + periodic refreshes while
-        # the port stays paused (how NS3 counts them)
-        frames = ((paused & ~carry["paused"])[:Lk].astype(jnp.float32)
-                  + paused[:Lk].astype(jnp.float32) * (dt / cfg.pause_resend))
-        pause_count = carry["pause_count"] + _reduce(plan.pause, pp["r_pause"],
-                                                     frames)
+        with jax.named_scope("s7_pfc"):
+            over = (q_port > xoff_l) & can
+            under = q_port < xon_l
+            paused = jnp.where(over, True,
+                               jnp.where(under, False, carry["paused"]))
+            # PAUSE frames: one on the off-transition + periodic refreshes
+            # while the port stays paused (how NS3 counts them)
+            frames = ((paused & ~carry["paused"])[:Lk].astype(jnp.float32)
+                      + paused[:Lk].astype(jnp.float32)
+                      * (dt / cfg.pause_resend))
+            pause_count = carry["pause_count"] + _reduce(
+                plan.pause, pp["r_pause"], frames)
 
         # ---- 8. completion --------------------------------------------------
-        wire_size = pp["size"] * wire
-        if faulty:
-            # duplicates arrive at the receiver and are discarded there:
-            # goodput = delivered - dup, so completion needs dup extra bytes
-            data_done = delivered >= wire_size + dup - cfg.eps_done
-        else:
-            data_done = delivered >= wire_size - cfg.eps_done
-        marker_done = (pp["n_hops"] == 0) & started
-        newly = ~carry["done"] & (jnp.where(pp["n_hops"] > 0, data_done, marker_done))
-        done = carry["done"] | newly
-        # completion happens at the END of this step's transfer window
-        t_finish = jnp.where(newly, t_end, carry["t_finish"])
-        g_count = carry["g_count"] + _reduce(plan.group, pp["r_group"],
-                                             newly.astype(jnp.float32))
-        g_done_new = (g_count >= pp["gsize"] - 0.5) & ~(carry["g_count"] >= pp["gsize"] - 0.5)
-        g_time = jnp.where(g_done_new, t_end, carry["g_time"])
+        with jax.named_scope("s8_completion"):
+            wire_size = pp["size"] * wire
+            if faulty:
+                # duplicates arrive at the receiver and are discarded there:
+                # goodput = delivered - dup, so completion needs dup extra
+                # bytes
+                data_done = delivered >= wire_size + dup - cfg.eps_done
+            else:
+                data_done = delivered >= wire_size - cfg.eps_done
+            marker_done = (pp["n_hops"] == 0) & started
+            newly = ~carry["done"] & (jnp.where(pp["n_hops"] > 0, data_done,
+                                                marker_done))
+            done = carry["done"] | newly
+            # completion happens at the END of this step's transfer window
+            t_finish = jnp.where(newly, t_end, carry["t_finish"])
+            g_count = carry["g_count"] + _reduce(plan.group, pp["r_group"],
+                                                 newly.astype(jnp.float32))
+            g_done_new = ((g_count >= pp["gsize"] - 0.5)
+                          & ~(carry["g_count"] >= pp["gsize"] - 0.5))
+            g_time = jnp.where(g_done_new, t_end, carry["g_time"])
 
         # ---- 9. history + soft cost ----------------------------------------
-        hist_q = lax.dynamic_update_slice_in_dim(
-            carry["hist_q"], q_link[None], it % plan.ring, axis=0)
-        hist_tx = lax.dynamic_update_slice_in_dim(
-            carry["hist_tx"], (tx_bytes / dt)[None], it % plan.ring, axis=0)
-        if faulty:
-            goodput = jnp.clip(delivered - dup, 0.0, wire_size)
-        else:
-            goodput = jnp.minimum(delivered, wire_size)
-        undeliv = jnp.sum(wire_size - goodput)
-        soft = carry["soft"] + dt * undeliv / jnp.maximum(jnp.sum(wire_size), 1.0)
+        with jax.named_scope("s9_history"):
+            hist_q = lax.dynamic_update_slice_in_dim(
+                carry["hist_q"], q_link[None], it % plan.ring, axis=0)
+            hist_tx = lax.dynamic_update_slice_in_dim(
+                carry["hist_tx"], (tx_bytes / dt)[None], it % plan.ring,
+                axis=0)
+            if faulty:
+                goodput = jnp.clip(delivered - dup, 0.0, wire_size)
+            else:
+                goodput = jnp.minimum(delivered, wire_size)
+            undeliv = jnp.sum(wire_size - goodput)
+            soft = carry["soft"] + dt * undeliv / jnp.maximum(
+                jnp.sum(wire_size), 1.0)
 
         # ---- 10. run health (observers; never touch the physics above) ------
-        # pause storm: >= storm_frac of pausable ports paused for
-        # storm_steps consecutive steps
-        n_pausable = jnp.maximum(
-            jnp.sum(pp["can_pause"][:Lk].astype(jnp.float32)), 1.0)
-        pfrac = jnp.sum(paused[:Lk].astype(jnp.float32)) / n_pausable
-        storm_run = jnp.where(pfrac >= cfg.storm_frac,
-                              carry["storm_run"] + 1, 0)
-        storm_step = jnp.where((carry["storm_step"] < 0)
-                               & (storm_run >= cfg.storm_steps),
-                               it, carry["storm_step"])
-        # pause-cycle deadlock: checked every deadlock_check_every steps
-        # while switch->switch pauses exist and no cycle was seen yet
-        dl_candidates = jnp.any(paused[:Lk] & pp["sw_sw"])
-        do_check = ((it % cfg.deadlock_check_every == 0) & dl_candidates
-                    & (carry["deadlock_step"] < 0))
-        if batched:
-            # per-lane predicate: select, see _make_run's lane_axis
-            cycle = do_check & _pause_cycle(paused)
-        else:
-            cycle = lax.cond(do_check, _pause_cycle,
-                             lambda _: jnp.zeros((), bool), paused)
-        deadlock_step = jnp.where(cycle & (carry["deadlock_step"] < 0),
-                                  it, carry["deadlock_step"])
-        # non-finite guard: freeze the lane at the first bad state instead
-        # of poisoning a whole vmapped batch (the step no-op gate and the
-        # early-exit loop both key on `diverged`)
-        probe = (jnp.sum(backlog) + jnp.sum(remaining) + jnp.sum(rate)
-                 + jnp.sum(q_link) + soft)
-        diverged = carry["diverged"] | ~jnp.isfinite(probe)
+        with jax.named_scope("s10_health"):
+            # pause storm: >= storm_frac of pausable ports paused for
+            # storm_steps consecutive steps
+            n_pausable = jnp.maximum(
+                jnp.sum(pp["can_pause"][:Lk].astype(jnp.float32)), 1.0)
+            pfrac = jnp.sum(paused[:Lk].astype(jnp.float32)) / n_pausable
+            storm_run = jnp.where(pfrac >= cfg.storm_frac,
+                                  carry["storm_run"] + 1, 0)
+            storm_step = jnp.where((carry["storm_step"] < 0)
+                                   & (storm_run >= cfg.storm_steps),
+                                   it, carry["storm_step"])
+            # pause-cycle deadlock: checked every deadlock_check_every steps
+            # while switch->switch pauses exist and no cycle was seen yet
+            dl_candidates = jnp.any(paused[:Lk] & pp["sw_sw"])
+            do_check = ((it % cfg.deadlock_check_every == 0) & dl_candidates
+                        & (carry["deadlock_step"] < 0))
+            if batched:
+                # per-lane predicate: select, see _make_run's lane_axis
+                cycle = do_check & _pause_cycle(paused)
+            else:
+                cycle = lax.cond(do_check, _pause_cycle,
+                                 lambda _: jnp.zeros((), bool), paused)
+            deadlock_step = jnp.where(cycle & (carry["deadlock_step"] < 0),
+                                      it, carry["deadlock_step"])
+            # non-finite guard: freeze the lane at the first bad state
+            # instead of poisoning a whole vmapped batch (the step no-op
+            # gate and the early-exit loop both key on `diverged`)
+            probe = (jnp.sum(backlog) + jnp.sum(remaining) + jnp.sum(rate)
+                     + jnp.sum(q_link) + soft)
+            diverged = carry["diverged"] | ~jnp.isfinite(probe)
 
         new_carry = dict(
             backlog=backlog, remaining=remaining, injected=injected,
@@ -863,12 +895,17 @@ def _make_step(policy: Policy, cfg: EngineConfig, plan: _Plan,
             new_carry["loss_sig"] = loss_sig
         if stride > 0:
             # strided timeline recording; rows for skipped steps are dropped
-            q_dev = _reduce(plan.qdev, pp["r_qdev"], q_link[:Lk])
-            row = jnp.where(it % stride == 0, it // stride, n_qrows)
-            new_carry["qbuf"] = carry["qbuf"].at[row].set(q_dev, mode="drop")
+            with jax.named_scope("s9_history"):
+                q_dev = _reduce(plan.qdev, pp["r_qdev"], q_link[:Lk])
+                row = jnp.where(it % stride == 0, it // stride, n_qrows)
+                new_carry["qbuf"] = carry["qbuf"].at[row].set(q_dev,
+                                                              mode="drop")
         return new_carry
 
     return step
+
+
+LANE_GATE = "engine_loop/lane_gate"
 
 
 def _make_run(policy: Policy, cfg: EngineConfig, plan: _Plan,
@@ -911,13 +948,19 @@ def _make_run(policy: Policy, cfg: EngineConfig, plan: _Plan,
         tab = _fabric_tables(pp, fab, flt, faulty)
 
         def body(c, it):
-            skip = jnp.all(c["done"]) | c["diverged"] | (it >= total)
+            # the gate runs under a named scope the benchmark reads, as the
+            # step's stages do (bench/stages.py ``op_stages``)
+            with jax.named_scope(LANE_GATE):
+                skip = jnp.all(c["done"]) | c["diverged"] | (it >= total)
             if lane_axis is not None:
                 new = step(c, it, pp, cc_params, flt, tab)
-                return jax.tree.map(lambda old, n: jnp.where(skip, old, n),
-                                    c, new), None
-            c2 = lax.cond(skip, lambda c: c,
-                          lambda c: step(c, it, pp, cc_params, flt, tab), c)
+                with jax.named_scope(LANE_GATE):
+                    return jax.tree.map(
+                        lambda old, n: jnp.where(skip, old, n), c, new), None
+            with jax.named_scope(LANE_GATE):
+                c2 = lax.cond(skip, lambda c: c,
+                              lambda c: step(c, it, pp, cc_params, flt, tab),
+                              c)
             return c2, None
 
         if not early_exit:

@@ -45,16 +45,17 @@ per-member ``(T, D)`` buffer); use a plain ``run`` for Fig 5-7 style plots.
 
 Backend note: vmap batching pays off where per-op dispatch overhead
 dominates — small/medium scenarios such as population autotuning and CC
-grid sweeps (measured ~2-4.5x over serial at B=8-16 on the dev container;
-see ``benchmarks/bench_engine.py``).  For very large gather-bound
+grid sweeps (measured ~2-4.5x over serial at B=8-16 on a CPU container;
+on the chip the benchmark is ``bench/run.py``, its readings in
+``PERF.md``).  For very large gather-bound
 scenarios on CPU the batched stepping loses its early-exit advantage (it
 runs until the *slowest* member finishes and computes both sides of the
 done-gate); on accelerator backends the batch dimension vectorizes fully.
 ``batch_pays_off``/``policy_axis_pays_off`` decide serial-vs-batched from
 the active backend's crossover table: ``calibrate_backend()`` measures it
-(serial vs batched at a few probe sizes, cached per backend, JSON records
-for BENCH_engine.json), ``DEFAULT_CROSSOVERS`` is the uncalibrated
-fallback.
+(serial vs batched at a few probe sizes, cached per backend,
+``BackendCalibration.record()`` gives its JSON form),
+``DEFAULT_CROSSOVERS`` is the uncalibrated fallback.
 
     runner = SweepRunner(EngineConfig(dt=2e-6, max_steps=4000, queue_stride=0))
     results = runner.run_policies(topo, sched, ["pfc", "dcqcn", "hpcc"])
@@ -116,6 +117,10 @@ class BatchResults:
     deadlock_step: np.ndarray | None = None   # (B,) first pause-cycle step
     storm_step: np.ndarray | None = None      # (B,) first pause-storm step
     extend_exhausted: np.ndarray | None = None  # (B,) budget ran out
+    # (B,) steps the lane's stepping loop ran: whole chunk_steps chunks
+    # (capped at the budget), shared by the lanes of one dispatched chunk
+    # on one device, as the loop runs while any of them is live
+    steps_run: np.ndarray | None = None
 
     @property
     def n(self) -> int:
@@ -231,22 +236,34 @@ def _warn_unhealthy_lanes(batch: "BatchResults", B: int) -> None:
 _LANES = "lanes"      # the vmap axis of sweep lanes (engine._make_run)
 
 
+def _span(name: str, call: int, lo: int | None = None, hi: int | None = None):
+    """A host span of ``run_batch`` call number ``call`` (and of its lanes
+    ``lo:hi`` where the batch is dispatched in chunks) in the profiler's
+    trace.  It records only while a ``jax.profiler`` trace is open; the
+    benchmark's readers name the device's idle time by these spans
+    (``bench/stages.py``), so their names are part of its yardstick."""
+    if lo is None:
+        return jax.profiler.TraceAnnotation(name, batch=call)
+    return jax.profiler.TraceAnnotation(name, batch=call, lo=lo, hi=hi)
+
+
 def _one_lane(policy: Policy, cfg: EngineConfig, plan, faulty: bool):
     """The per-lane body both batch paths vmap over (axis ``_LANES``):
     build a fresh carry, run the jitted stepping loop (which donates it
-    internally) and keep only the per-lane finals."""
+    internally) and keep only the per-lane finals and the steps the loop
+    ran."""
     run = _make_run(policy, cfg, plan, early_exit=True, faulty=faulty,
                     lane_axis=_LANES)
 
     def one(pp, params, fab, flt):
         carry = _init_carry(pp, plan, policy, cfg, params, faulty)
-        carry, _ = run(carry, pp, params, fab, flt)
+        carry, steps_run = run(carry, pp, params, fab, flt)
         out = {"t_finish": carry["t_finish"], "done": carry["done"],
                "pause_count": carry["pause_count"],
                "delivered": carry["delivered"], "soft": carry["soft"],
                "diverged": carry["diverged"],
                "deadlock_step": carry["deadlock_step"],
-               "storm_step": carry["storm_step"]}
+               "storm_step": carry["storm_step"], "steps_run": steps_run}
         if faulty:
             out["lost"] = carry["lost"]
         return out
@@ -447,8 +464,8 @@ _INF = float("inf")
 # "policy_axis" = stacked lax.switch product policy vs per-policy runs;
 # "sharded" = the shard_map grid layout vs the single-device vmap (only
 # measurable with >1 device; unlisted -> inf, i.e. shard whenever a mesh
-# was configured).  CPU numbers are from BENCH_engine.json on the dev
-# container (the sweep wins 4-5x below ~2k flows and loses 0.3x on the
+# was configured).  CPU numbers were measured on a CPU container by
+# calibrate_backend (the sweep wins 4-5x below ~2k flows and loses 0.3x on the
 # 7936-flow All-Reduce; the policy axis loses at every measured CPU
 # scale).  Backends not listed (TPU/GPU) vectorize the batch axis fully,
 # so batching always pays off there (inf).
@@ -479,7 +496,7 @@ class BackendCalibration:
         return n_flows <= thr
 
     def record(self) -> dict:
-        """JSON-safe dict for BENCH_engine.json (inf encoded as "inf")."""
+        """JSON-safe dict (inf encoded as "inf")."""
         enc = {k: ("inf" if float(v) == _INF else float(v))
                for k, v in self.crossover.items()}
         return {"backend": self.backend, "source": self.source,
@@ -604,8 +621,8 @@ def get_calibration(backend: str | None = None) -> BackendCalibration:
 
 
 def set_calibration(cal: BackendCalibration) -> None:
-    """Install a crossover table for ``cal.backend`` (e.g. one loaded from
-    a previous BENCH_engine.json record)."""
+    """Install a crossover table for ``cal.backend`` (e.g. one built from
+    an earlier ``BackendCalibration.record()``)."""
     _CALIBRATION[cal.backend] = cal
 
 
@@ -711,8 +728,8 @@ def calibrate_backend(probe_flows=(90, 1806), B: int = 6,
     ``calibration_cache_path()`` (``persist=False`` to skip) so later
     processes warm-start via ``get_calibration`` instead of re-measuring.
     ``_measure(kind, n_flows, B, cfg)`` is injectable for tests and
-    deterministic benchmarks; ``BackendCalibration.record()`` gives the
-    JSON form ``benchmarks/bench_engine.py`` writes to BENCH_engine.json.
+    deterministic benchmarks; ``BackendCalibration.record()`` gives its
+    JSON form.
     """
     backend = backend or jax.default_backend()
     cfg = cfg or EngineConfig(dt=2e-6, max_steps=600, max_extends=1,
@@ -778,6 +795,7 @@ class SweepRunner:
         # point (an exception raised here aborts the dispatch exactly like
         # an XLA OOM/compile failure would) and kill/progress probe
         self.dispatch_hook = dispatch_hook
+        self._calls = 0               # run_batch calls, for the host spans
 
     def _pre_dispatch(self, lo: int, hi: int, B: int) -> None:
         if self.dispatch_hook is not None:
@@ -873,7 +891,7 @@ class SweepRunner:
         additionally evaluates *every* member's update per lane (vmapped
         ``lax.switch`` runs all branches).  Called without ``sched`` the
         axis is recommended only where it wins at every measured scale: on
-        CPU it loses wall-clock everywhere (BENCH_engine.json policy_axis)
+        CPU it loses wall-clock everywhere (measured by calibrate_backend)
         — the win there is architectural (one compile, zero recompiles
         across policy x param x fabric grids), not wall-clock."""
         return get_calibration().pays_off(
@@ -1019,13 +1037,16 @@ class SweepRunner:
         # whether to construct a mesh, mirroring batch_pays_off — run_batch
         # never second-guesses its caller.
         lanes = (full, fab, flt)
+        call = self._calls
         if self.mesh is None:
             fn = _compiled_batch(policy, cfg, sim.plan, faulty)
             chunk = self._chunk_size(B)
             if chunk >= B:                        # one dispatch, no padding
                 self._pre_dispatch(0, B, B)
-                out = fn(sim.pp, *lanes)
-                return jax.tree.map(np.asarray, out)
+                with _span("sweep.dispatch", call):
+                    out = fn(sim.pp, *lanes)
+                with _span("sweep.pull", call):
+                    return jax.tree.map(np.asarray, out)
             parts, pending = [], None
             for lo in range(0, B, chunk):
                 hi = min(lo + chunk, B)
@@ -1034,15 +1055,18 @@ class SweepRunner:
                     take = np.concatenate(
                         [take, np.full(chunk - (hi - lo), hi - 1)])
                 self._pre_dispatch(lo, hi, B)
-                got = fn(sim.pp, *jax.tree.map(lambda a: a[take], lanes))
+                with _span("sweep.dispatch", call, lo, hi):
+                    got = fn(sim.pp, *jax.tree.map(lambda a: a[take], lanes))
                 if pending is not None:
                     lo0, hi0, out0 = pending
-                    parts.append(jax.tree.map(
-                        lambda a: np.asarray(a)[:hi0 - lo0], out0))
+                    with _span("sweep.pull", call, lo0, hi0):
+                        parts.append(jax.tree.map(
+                            lambda a: np.asarray(a)[:hi0 - lo0], out0))
                 pending = (lo, hi, got)
             lo0, hi0, out0 = pending
-            parts.append(jax.tree.map(
-                lambda a: np.asarray(a)[:hi0 - lo0], out0))
+            with _span("sweep.pull", call, lo0, hi0):
+                parts.append(jax.tree.map(
+                    lambda a: np.asarray(a)[:hi0 - lo0], out0))
             return jax.tree.map(
                 lambda *xs: np.concatenate(xs, axis=0), *parts)
         n_dev = self.n_mesh_devices
@@ -1061,15 +1085,19 @@ class SweepRunner:
                 take = np.concatenate(
                     [take, np.full(chunk - (hi - lo), hi - 1)])
             self._pre_dispatch(lo, hi, B)
-            got = fn(sim.pp, *jax.tree.map(lambda a: a[take[order]], lanes))
+            with _span("sweep.dispatch", call, lo, hi):
+                got = fn(sim.pp,
+                         *jax.tree.map(lambda a: a[take[order]], lanes))
             if pending is not None:               # stream: gather the chunk
                 lo0, hi0, out0 = pending          # dispatched *last* round
-                parts.append(jax.tree.map(
-                    lambda a: np.asarray(a)[inv][:hi0 - lo0], out0))
+                with _span("sweep.pull", call, lo0, hi0):
+                    parts.append(jax.tree.map(
+                        lambda a: np.asarray(a)[inv][:hi0 - lo0], out0))
             pending = (lo, hi, got)
         lo0, hi0, out0 = pending
-        parts.append(jax.tree.map(
-            lambda a: np.asarray(a)[inv][:hi0 - lo0], out0))
+        with _span("sweep.pull", call, lo0, hi0):
+            parts.append(jax.tree.map(
+                lambda a: np.asarray(a)[inv][:hi0 - lo0], out0))
         if len(parts) == 1:
             return parts[0]
         return jax.tree.map(lambda *xs: np.concatenate(xs, axis=0), *parts)
@@ -1158,36 +1186,41 @@ class SweepRunner:
         deadlocked or budget-exhausted lane is flagged, and the healthy
         lanes complete normally — see ``BatchResults.lane_status``.
         """
-        policy, cfg, sim, full, fab, flt, faulty, B = self._batch_inputs(
-            topo, sched, policy, stacked_params, stacked_fabric,
-            fabric_params, cc_params, cfg, stacked_fault, fault_spec)
+        self._calls += 1
+        call = self._calls
+        with _span("sweep.inputs", call):
+            policy, cfg, sim, full, fab, flt, faulty, B = self._batch_inputs(
+                topo, sched, policy, stacked_params, stacked_fabric,
+                fabric_params, cc_params, cfg, stacked_fault, fault_spec)
         out = self._dispatch_lanes(policy, cfg, sim, full, fab, flt,
                                    faulty, B)
-        F = sim.plan.n_flows
-        t_fin = np.asarray(out["t_finish"])[:, :F]
-        done = np.asarray(out["done"])[:, :F]
-        ct = np.max(np.where(np.isfinite(t_fin), t_fin, 0.0), axis=1)
-        finished = done.all(axis=1)
-        diverged = np.asarray(out["diverged"])
-        deadlock_step = np.asarray(out["deadlock_step"])
-        storm_step = np.asarray(out["storm_step"])
-        extend_exhausted = ~finished & ~diverged
-        batch = BatchResults(
-            policy=policy.name, params=full,
-            fabric={k: np.asarray(getattr(fab, k))
-                    for k in FabricParams.FIELDS},
-            completion_time=ct, t_finish=t_fin,
-            pause_count=np.asarray(out["pause_count"]),
-            delivered=np.asarray(out["delivered"])[:, :F],
-            soft_cost=np.asarray(out["soft"]),
-            finished=finished,
-            policy_axis=tuple(policy_axis),
-            fault=({k: np.asarray(getattr(flt, k))
-                    for k in FaultSpec.FIELDS} if faulty else {}),
-            diverged=diverged, deadlock_step=deadlock_step,
-            storm_step=storm_step, extend_exhausted=extend_exhausted,
-        )
-        _warn_unhealthy_lanes(batch, B)
+        with _span("sweep.results", call):
+            F = sim.plan.n_flows
+            t_fin = np.asarray(out["t_finish"])[:, :F]
+            done = np.asarray(out["done"])[:, :F]
+            ct = np.max(np.where(np.isfinite(t_fin), t_fin, 0.0), axis=1)
+            finished = done.all(axis=1)
+            diverged = np.asarray(out["diverged"])
+            deadlock_step = np.asarray(out["deadlock_step"])
+            storm_step = np.asarray(out["storm_step"])
+            extend_exhausted = ~finished & ~diverged
+            batch = BatchResults(
+                policy=policy.name, params=full,
+                fabric={k: np.asarray(getattr(fab, k))
+                        for k in FabricParams.FIELDS},
+                completion_time=ct, t_finish=t_fin,
+                pause_count=np.asarray(out["pause_count"]),
+                delivered=np.asarray(out["delivered"])[:, :F],
+                soft_cost=np.asarray(out["soft"]),
+                finished=finished,
+                policy_axis=tuple(policy_axis),
+                fault=({k: np.asarray(getattr(flt, k))
+                        for k in FaultSpec.FIELDS} if faulty else {}),
+                diverged=diverged, deadlock_step=deadlock_step,
+                storm_step=storm_step, extend_exhausted=extend_exhausted,
+                steps_run=np.asarray(out["steps_run"]),
+            )
+            _warn_unhealthy_lanes(batch, B)
         return batch
 
     def grid(self, topo, sched, policy: Policy | str | None = None,

@@ -136,5 +136,7 @@ def fused_signals_policy_tiled(policy, hop_inputs: tuple, flat_inputs: tuple,
         out_specs=[st_spec] + [flat_spec] * 5,
         out_shape=out_shape,
         interpret=interpret,
+        # a stable name for the trace, whatever the call site's HLO name
+        name="engine_step_signals_cc",
     )(*hop_inputs, *flat_inputs, state4d, params2d.reshape(B, 1, P),
       jnp.asarray(t, jnp.float32).reshape(1, 1))

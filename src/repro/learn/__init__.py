@@ -19,4 +19,4 @@ from repro.learn.net import (HIDDEN, N_FEATURES, WEIGHT_KEYS,  # noqa: F401
 from repro.learn.train import (LearnResult, TrainConfig,  # noqa: F401
                                curriculum_default, heldout_default,
                                heldout_eval, load_checkpoint,
-                               save_checkpoint, train, train_smoke)
+                               save_checkpoint, train)

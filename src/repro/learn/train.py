@@ -292,27 +292,6 @@ def train(cfg: TrainConfig = TrainConfig(), curriculum: list | None = None,
     return res
 
 
-def train_smoke(steps: int = 5) -> dict:
-    """Tiny single-scenario training loop for ``bench_engine.py --smoke``:
-    returns the loss trajectory and measured steps/s."""
-    cfg = TrainConfig(steps=steps, lr=0.08)
-    engine_cfg = EngineConfig(dt=2e-6, max_steps=1200, max_extends=0,
-                              queue_stride=0)
-    spec = ScenarioSpec(_single(8), IncastSpec(7, 1e6), "mlp",
-                        name="smoke_incast8")
-    task = make_task(spec, engine_cfg=engine_cfg, corners=(None,),
-                     remat=True, train_cfg=cfg)
-    t0 = time.time()
-    res = train(cfg, tasks=[task])
-    wall = time.time() - t0
-    losses = [h["loss"] for h in res.history]
-    return {"steps": steps, "loss_first": losses[0], "loss_last": losses[-1],
-            "loss_decreased": bool(losses[-1] < losses[0]),
-            "nonfinite_steps": sum(h["nonfinite"] for h in res.history),
-            "steps_per_s": round(steps / wall, 3),
-            "wall_s": round(wall, 2)}
-
-
 # ---------------------------------------------------------------------------
 # held-out evaluation: the trained policy vs every classical policy
 # ---------------------------------------------------------------------------
