@@ -285,9 +285,10 @@ def _next_pow2(n: int) -> int:
     return 1 << max(int(n) - 1, 0).bit_length() if n > 1 else 1
 
 
-# single-level padded-gather width cap: segments with more members than
-# this use the two-level split-row plan so one hot port (e.g. a full-fabric
-# incast) cannot inflate the gather to n_out * max_count slots
+# split-row block width of the two-level plan, which a reduction takes
+# whenever it gathers fewer rows than the single-level one: one hot port
+# (e.g. a full-fabric incast) cannot inflate the gather to n_out * max_count
+# slots, nor a few busy segments among many idle ones
 _SPLIT_C = 64
 
 
@@ -313,9 +314,12 @@ def _reduce_plan(ids: np.ndarray, n_in: int, n_out: int,
     Three strategies, chosen statically from the (known) fan-in histogram:
       empty    no live entries — the reduction is identically zero
       gather   (n_out, C) padded gather + row sum, C = max segment size
+               (pow2)
       gather2  split-row: each segment padded to a multiple of _SPLIT_C,
                one flat gather + block sum, then a tiny second-level
                padded gather over per-block partial sums
+    Of the last two, the one that gathers fewer rows in all (single level
+    on a tie).
     """
     ids = np.asarray(ids, np.int64).reshape(-1)
     keep = np.ones(ids.shape, bool) if drop is None else ~np.asarray(drop).reshape(-1)
@@ -325,21 +329,21 @@ def _reduce_plan(ids: np.ndarray, n_in: int, n_out: int,
         return {}, ("empty", n_out)
     counts = np.bincount(kept_ids, minlength=n_out)
     C = _next_pow2(int(counts.max()))
-    if C <= _SPLIT_C:
+    nblk = -(-counts // _SPLIT_C)                  # ceil; 0 for empty segments
+    blk_start = np.concatenate([[0], np.cumsum(nblk)])
+    n_blocks = int(blk_start[-1])
+    C2 = _next_pow2(int(nblk.max()))
+    if n_out * C <= n_blocks * _SPLIT_C + n_out * C2:
         idx = _padded_rows(kept_ids, kept_pos, counts, n_out, n_in, C)
         return {"idx": jnp.asarray(idx.reshape(-1), jnp.int32)}, \
             ("gather", n_out, C)
     # split-row: block-align each segment to _SPLIT_C-wide sub-rows
-    nblk = -(-counts // _SPLIT_C)                  # ceil; 0 for empty segments
-    blk_start = np.concatenate([[0], np.cumsum(nblk)])
-    n_blocks = int(blk_start[-1])
     perm = np.full(n_blocks * _SPLIT_C, n_in, np.int64)
     order = np.argsort(kept_ids, kind="stable")
     starts = np.concatenate([[0], np.cumsum(counts)])
     for s in np.nonzero(counts)[0]:
         lo = blk_start[s] * _SPLIT_C
         perm[lo:lo + counts[s]] = kept_pos[order[starts[s]:starts[s] + counts[s]]]
-    C2 = _next_pow2(int(nblk.max()))
     bidx = np.full((n_out, C2), n_blocks, np.int64)
     for s in np.nonzero(nblk)[0]:
         bidx[s, :nblk[s]] = np.arange(blk_start[s], blk_start[s + 1])
@@ -364,6 +368,29 @@ def _reduce(strategy, arrs, vals):
     return rows.reshape(n_out, C2).sum(axis=1)
 
 
+def _queues(plan: "_Plan", pp: dict, backlog):
+    """Stage 6: ``(q_link, q_port)`` from the ``(F, MAXHOP)`` backlog.
+
+    ``q_link[l]`` is the backlog queued at link ``l`` over every hop;
+    ``q_port[l]`` the occupancy of ingress port ``l`` at the receiving
+    switch, where hop ``h >= 1`` arrived via link ``path[:, h-1]`` (hop-0
+    backlog is the host's own send queue).  Each plan reduces one
+    contiguous hop column, never the flattened backlog: in a vmapped batch
+    the compiler leaves that in HBM, where a gather costs several times as
+    much per row as from VMEM.
+    """
+    q_link = jnp.zeros((plan.n_links + 1,), jnp.float32)
+    q_port = q_link
+    for h in range(MAXHOP):
+        if plan.hop[h][0] != "empty":
+            q_link = q_link + _reduce(plan.hop[h], pp["r_hop"][h],
+                                      backlog[:, h])
+        if h >= 1 and plan.ingress[h - 1][0] != "empty":
+            q_port = q_port + _reduce(plan.ingress[h - 1],
+                                      pp["r_ingress"][h - 1], backlog[:, h])
+    return q_link, q_port
+
+
 @dataclasses.dataclass(frozen=True)
 class _Plan:
     """Hashable static description of one prepared scenario.
@@ -380,8 +407,7 @@ class _Plan:
     n_dev: int
     ring: int                     # feedback history slots (pow2)
     hop: tuple                    # per-hop demand reduction strategies
-    qlink: tuple
-    qport: tuple
+    ingress: tuple                # per-hop ingress-port strategies, h >= 1
     group: tuple
     pause: tuple
     qdev: tuple
@@ -486,10 +512,14 @@ def _prep(topo: Topology, sched: Schedule, cfg: EngineConfig,
         a, s = _reduce_plan(path[:, h], Fp, Lk + 1, drop=invalid[:, h])
         hop_arrs.append(a)
         hop_strats.append(s)
-    ql_a, ql_s = _reduce_plan(path.reshape(-1), Fp * MAXHOP, Lk + 1,
-                              drop=invalid.reshape(-1))
-    qp_a, qp_s = _reduce_plan(ingress.reshape(-1), Fp * MAXHOP, Lk + 1,
-                              drop=(ingress == Lk).reshape(-1))
+    # stage 6 reduces one contiguous hop column at a time: q_link sums the
+    # hop plans above, q_port these ingress plans (hop 0 has no ingress)
+    ing_arrs, ing_strats = [], []
+    for h in range(1, MAXHOP):
+        a, s = _reduce_plan(ingress[:, h], Fp, Lk + 1,
+                            drop=ingress[:, h] == Lk)
+        ing_arrs.append(a)
+        ing_strats.append(s)
     gr_a, gr_s = _reduce_plan(group, Fp, Gp, drop=~active)
     pa_a, pa_s = _reduce_plan(dst_dev[:Lk], Lk, topo.n_devices)
     qd_a, qd_s = _reduce_plan(topo.src_dev, Lk, topo.n_devices)
@@ -499,7 +529,7 @@ def _prep(topo: Topology, sched: Schedule, cfg: EngineConfig,
     plan = _Plan(
         n_flows=F, n_flows_pad=Fp, n_groups=G, n_groups_pad=Gp,
         n_links=Lk, n_dev=topo.n_devices, ring=ring,
-        hop=tuple(hop_strats), qlink=ql_s, qport=qp_s,
+        hop=tuple(hop_strats), ingress=tuple(ing_strats),
         group=gr_s, pause=pa_s, qdev=qd_s,
     )
     pp = dict(
@@ -525,7 +555,7 @@ def _prep(topo: Topology, sched: Schedule, cfg: EngineConfig,
         gsize=jnp.asarray(gsize),
         active=jnp.asarray(active),
         dev_buf=jnp.asarray(topo.dev_buf.astype(np.float32)),
-        r_hop=tuple(hop_arrs), r_qlink=ql_a, r_qport=qp_a,
+        r_hop=tuple(hop_arrs), r_ingress=tuple(ing_arrs),
         r_group=gr_a, r_pause=pa_a, r_qdev=qd_a,
     )
     return pp, plan
@@ -789,14 +819,12 @@ def _make_step(policy: Policy, cfg: EngineConfig, plan: _Plan,
 
         # ---- 6. queues ------------------------------------------------------
         with jax.named_scope("s6_queues"):
-            q_link = _reduce(plan.qlink, pp["r_qlink"], backlog.reshape(-1))
+            q_link, q_port = _queues(plan, pp, backlog)
             xoff_l, xon_l = tab["xoff_l"], tab["xon_l"]
             can = pp["can_pause"]
             if faulty:
                 # PFC misconfiguration / lossy-RoCE: pfc_on=0 disables pausing
                 can = can & (tab["pfc_on_l"] > 0.5)
-            # per-ingress-port occupancy at the receiving switch
-            q_port = _reduce(plan.qport, pp["r_qport"], backlog.reshape(-1))
 
         # ---- 7. PFC per-port hysteresis -------------------------------------
         with jax.named_scope("s7_pfc"):
