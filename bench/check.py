@@ -1,5 +1,6 @@
 """The comparison that decides ``correct``: lanes the timed dispatches
-produced against the plain reference (``bench/reference.py``).
+produced against the configuration's plain reference (the module its
+``reference`` key names; see ``bench/modules.py``).
 
 Numbers compared, per sampled lane (the largest over the sample counts):
 

@@ -9,7 +9,7 @@ pool of worker processes (every seed's lanes together), every lane of
 the batch:
 
 * ``sound``: each seed's lanes, as the program computed them, against the
-  float32 reference;
+  configuration's reference (its ``reference`` module) in float32;
 * ``control``: for the control seeds, the reference computed in bfloat16,
   the precision below the configuration's float32, put in the program's
   place and compared the same way.  A control lane runs to twice the steps
@@ -34,7 +34,7 @@ BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
 sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
-from bench import check, reference  # noqa: E402
+from bench import check, modules  # noqa: E402
 from bench.lanes import make_lanes  # noqa: E402
 from bench.run import (Program, load_cell, say,  # noqa: E402
                        use_compilation_cache)
@@ -68,7 +68,7 @@ def main(argv=None) -> int:
     prog = None
     for seed in seeds:
         lanes = make_lanes(mix, seed)
-        prog = Program(config, mix, lanes)
+        prog = Program(config, mix, lanes, cell["bench"])
         if not runs:
             prog.compile()
         t0 = time.perf_counter()
@@ -90,7 +90,8 @@ def main(argv=None) -> int:
             args.workers or os.cpu_count() or 1,
             mp_context=multiprocessing.get_context("spawn")) as pool, \
             open(args.out, "a") as out:
-        sound = {s: [pool.submit(reference.run_lane, config, ln)
+        sound = {s: [pool.submit(modules.reference_lane, cell["bench"],
+                                 config, ln)
                      for ln in runs[s][0]] for s in seeds}
         low = {}
         for s in seeds:
@@ -104,9 +105,10 @@ def main(argv=None) -> int:
                          "elapsed_s": time.perf_counter() - t0,
                          **check.worst(nums), "lanes": nums})
             if s in control_seeds:
-                low[s] = (wants, [pool.submit(reference.run_lane, config, ln,
-                                              "bfloat16", 2 * w["steps"])
-                                  for ln, w in zip(lanes, wants)])
+                low[s] = (wants, [pool.submit(
+                    modules.reference_lane, cell["bench"], config, ln,
+                    "bfloat16", 2 * w["steps"])
+                    for ln, w in zip(lanes, wants)])
         for s, (wants, futs) in low.items():
             lows = [f.result() for f in futs]
             nums = [check.lane_numbers(lo, w, dt, 2 * w["steps"])

@@ -8,6 +8,13 @@ multiplies each lane's key parameter, kmin, kmax and xoff by factors drawn
 log-uniform in the mix's ``seed_factor`` range, then clips the key
 parameter to its bounds; seed 0 draws no factors, so it gives the grid
 itself.
+
+A policy named in the mix's ``seeded_params`` (``{policy: {"keys": [...],
+"scale": s}}``) gets each listed parameter drawn uniform in [-s, s] from
+the seed, on every seed (seed 0 too), from a stream of its own: the
+factors above, and so every lane of a mix without ``seeded_params``, are
+what they were before such draws existed.  The learned ``mlp`` policy's
+weights come from the seed this way.
 """
 from __future__ import annotations
 
@@ -39,6 +46,8 @@ def make_lanes(mix: dict, seed: int) -> list[Lane]:
     else:
         rng = np.random.default_rng(seed)
         fac = np.exp(rng.uniform(np.log(lo), np.log(hi), (len(rows), 4)))
+    seeded = mix.get("seeded_params", {})
+    wrng = np.random.default_rng([seed, 1]) if seeded else None
     lanes = []
     for (pol, key, s, kmin, kmax, xoff), f in zip(rows, fac):
         params = {}
@@ -47,6 +56,12 @@ def make_lanes(mix: dict, seed: int) -> list[Lane]:
             v = min(max(d * s, klo), khi)
             v = min(max(v * f[0], klo), khi)
             params = {key["name"]: float(np.float32(v))}
+        if pol in seeded:
+            draw = seeded[pol]
+            vals = wrng.uniform(-draw["scale"], draw["scale"],
+                                len(draw["keys"]))
+            params.update({k: float(np.float32(v))
+                           for k, v in zip(draw["keys"], vals)})
         lanes.append(Lane(pol, params, float(np.float32(kmin * f[1])),
                           float(np.float32(kmax * f[2])),
                           float(np.float32(xoff * f[3]))))

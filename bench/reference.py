@@ -4,7 +4,8 @@ A straightforward NumPy implementation of the fluid RoCE fabric model the
 program simulates: the two-tier CLOS of the paper's Section III-B, per-flow
 ECMP, the topology-aware ring all-reduce and the direct all-to-all, the
 classical congestion-control policies (pfc, dcqcn, dctcp, timely, hpcc,
-hpcc_pint, static_window), and the fixed-timestep fluid step
+hpcc_pint, static_window) and the learned one (mlp, on the weights the
+lane gives), and the fixed-timestep fluid step
 (delayed signals, CC update, paced injection, hop-ordered forwarding with
 proportional drain, PFC per-port hysteresis with PAUSE frame counts,
 dependency groups, the pause-cycle deadlock observer).  It imports nothing
@@ -420,9 +421,72 @@ class StaticWindow(_Policy):
         return st, sig["line"], st["w"]
 
 
+# the learned policy's net: 6 features, 4 hidden units, 2 heads; weights
+# w1_{j}{i}, b1_{j} per hidden unit j, then w2_{o}{j}, b2_{o} per head o
+# (0: rate, 1: window), 38 in all
+MLP_FEATURES, MLP_HIDDEN = 6, 4
+MLP_WEIGHTS = tuple(
+    [k for j in range(MLP_HIDDEN)
+     for k in [f"w1_{j}{i}" for i in range(MLP_FEATURES)] + [f"b1_{j}"]]
+    + [k for o in range(2)
+       for k in [f"w2_{o}{j}" for j in range(MLP_HIDDEN)] + [f"b2_{o}"]])
+
+
+class Mlp(_Policy):
+    """The learned policy: a per-flow net of one hidden layer (tanh) over
+    six bounded features (ECN mark fraction, d/(1+d) of the queueing delay
+    d over the base RTT, u/(1+u) of the INT utilisation, rate / line,
+    window / (window + 4 BDP), 1 / fan-in) and two heads: a rate target
+    line * sigmoid(s_r + 4) and a window target, the static window prior
+    max(2 BDP / fan-in + 0.5 MB / fan-in, 4000) times exp(2.5 tanh(s_w)).
+    Rate and window track their targets at RTT timescale, a = out_gain *
+    dt / max(base RTT, dt) clipped to [0, 1], then clip to [1e-3 line,
+    line] and [1000, 32 BDP].  Weights the lane leaves out are 0 (the net
+    then gives the prior).  The loss cut is left out: the reference fabric
+    loses nothing."""
+    defaults = dict(out_gain=1.0, loss_cut=1.0,
+                    **dict.fromkeys(MLP_WEIGHTS, 0.0))
+
+    def init(self, ctx):
+        f = np.maximum(ctx["fanin"], 1.0)
+        win0 = np.maximum(2.0 * ctx["bdp"] / f + 0.5e6 / f, 4000.0)
+        return {"rate": ctx["line"].copy(), "win": win0,
+                "bdp": ctx["bdp"].copy(), "fanin": f}
+
+    def update(self, p, st, sig):
+        line = np.maximum(sig["line"], 1.0)
+        base = np.maximum(sig["base_rtt"], 1e-7)
+        bdp = np.maximum(st["bdp"], 1.0)
+        qd = np.maximum(sig["rtt"] - sig["base_rtt"], 0.0) / base
+        u = np.maximum(sig["util"], 0.0)
+        f = np.maximum(st["fanin"], 1.0)
+        x = (sig["ecn"], qd / (1.0 + qd), u / (1.0 + u), st["rate"] / line,
+             st["win"] / (st["win"] + 4.0 * bdp), 1.0 / f)
+        s_r, s_w = 0, 0
+        for j in range(MLP_HIDDEN):
+            z = 0
+            for i in range(MLP_FEATURES):
+                z = z + p[f"w1_{j}{i}"] * x[i]
+            h = np.tanh(z + p[f"b1_{j}"])
+            s_r = s_r + p[f"w2_0{j}"] * h
+            s_w = s_w + p[f"w2_1{j}"] * h
+        s_r, s_w = s_r + p["b2_0"], s_w + p["b2_1"]
+        win_prior = np.maximum(2.0 * bdp / f + 0.5e6 / f, 4000.0)
+        rate_tgt = line * (1.0 / (1.0 + np.exp(-(s_r + 4.0))))
+        win_tgt = win_prior * np.exp(2.5 * np.tanh(s_w))
+        a = np.clip(p["out_gain"] * sig["dt"]
+                    / np.maximum(base, sig["dt"]), 0.0, 1.0)
+        rate = np.clip(st["rate"] + a * (rate_tgt - st["rate"]),
+                       1e-3 * line, line)
+        win = np.clip(st["win"] + a * (win_tgt - st["win"]), 1000.0,
+                      32.0 * bdp)
+        return ({"rate": rate, "win": win, "bdp": st["bdp"],
+                 "fanin": st["fanin"]}, rate, win)
+
+
 POLICIES = {"pfc": Pfc, "dcqcn": Dcqcn, "dctcp": Dctcp, "timely": Timely,
             "hpcc": Hpcc, "hpcc_pint": HpccPint,
-            "static_window": StaticWindow}
+            "static_window": StaticWindow, "mlp": Mlp}
 
 
 def policy_defaults(name: str) -> dict:

@@ -3,14 +3,16 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A cell names a configuration (``bench/configs/<config>.json``: fabric,
-collective, engine settings) and a traffic mix (``bench/traffic/<mix>.json``:
-the sweep's policies, parameter span, fabric corners, entry point and
-mesh).  The harness finds both by name, builds the scenario and the lanes
-from them and the seed, compiles the cell's one executable ahead of time,
-then dispatches the whole batch again and again while the window is open:
-each dispatch is one ``SweepRunner.run_batch`` call that returns host
-arrays, and the window closes when the last one has
-returned.  A compile inside the window fails the run.
+collective, engine settings, and the names of its scenario module under
+``bench/scenarios/`` and its reference module under ``bench/``) and a
+traffic mix (``bench/traffic/<mix>.json``: the sweep's policies, parameter
+span, fabric corners, entry point and mesh).  The harness finds all of
+them by name, builds the scenario and the lanes from them and the seed,
+compiles the cell's one executable ahead of time, then dispatches the
+whole batch again and again while the window is open: each dispatch is
+one call of the mix's entry (``ENTRIES``: ``SweepRunner.run_batch`` or
+``run_policy_axis``) that returns host arrays, and the window closes when
+the last one has returned.  A compile inside the window fails the run.
 
 ``--trace 0`` reports the end-to-end metrics: ``lane_steps_per_s`` (every
 lane's simulated steps up to its own end, summed over the window's
@@ -20,7 +22,7 @@ reports the cell's per-layer metrics, each read by its own
 ``bench/metrics/<metric>.py``.
 
 ``correct``: every lane of the batch, as every dispatch of the window
-returned it, against the plain reference (``bench/reference.py``), run on
+returned it, against the configuration's plain reference, run on
 the host after the window in a pool of worker processes, one lane each;
 ``bench/limits/<cell>.json`` holds the limits.  The
 numbers compared and their limits are the last lines of standard error and
@@ -37,7 +39,6 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import concurrent.futures as cf  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import multiprocessing  # noqa: E402
 import os  # noqa: E402
@@ -50,7 +51,7 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 import numpy as np  # noqa: E402
 
-from bench import check, reference  # noqa: E402
+from bench import check, modules  # noqa: E402
 from bench.lanes import make_lanes  # noqa: E402
 from bench.roofline import stage12_bytes, stage12_flops  # noqa: E402
 
@@ -73,6 +74,7 @@ def load_cell(name: str, root: str = ROOT) -> dict:
         raise SystemExit(f"no workload {name!r} in BENCHMARK.json")
     bench = os.path.join(root, "bench")
     return {
+        "bench": bench,
         "cell": cell,
         "config": load_json(bench, "configs", f"{cell['config']}.json"),
         "mix": load_json(bench, "traffic", f"{cell['traffic']}.json"),
@@ -84,38 +86,64 @@ def load_cell(name: str, root: str = ROOT) -> dict:
 
 
 def read_metric(name: str, run: dict):
-    path = os.path.join(BENCH, "metrics", f"{name}.py")
-    mod_spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                      path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read(run)
+    return modules.load(os.path.join(BENCH, "metrics", f"{name}.py")).read(
+        run)
 
 
 # ---------------------------------------------------------------------------
 # the system under test
 # ---------------------------------------------------------------------------
 
+def _entry_run_batch(runner, topo, sched, lanes, fab):
+    """``run_batch``: the lanes share one policy and stack its parameters."""
+    pols = {ln.policy for ln in lanes}
+    if len(pols) != 1:
+        raise ValueError("run_batch lanes share one policy")
+    pol = pols.pop()
+    params = {key: np.asarray([ln.params[key] for ln in lanes], np.float32)
+              for key in lanes[0].params}
+    return (pol,
+            lambda: runner.compile_batch(topo, sched, pol, params,
+                                         stacked_fabric=fab),
+            lambda: runner.run_batch(topo, sched, pol, params,
+                                     stacked_fabric=fab))
+
+
+def _entry_run_policy_axis(runner, topo, sched, lanes, fab):
+    """``run_policy_axis``: lane i runs member i of the stacked policy,
+    with the lane's parameters as that member's overrides."""
+    from repro.core.sweep import stack_policy_axis
+    pols = [ln.policy for ln in lanes]
+    overrides = [dict(ln.params) for ln in lanes]
+    stacked, params, _ = stack_policy_axis(pols, overrides)
+    return (None,
+            lambda: runner.compile_batch(topo, sched, stacked, params,
+                                         stacked_fabric=fab),
+            lambda: runner.run_policy_axis(topo, sched, pols, overrides,
+                                           stacked_fabric=fab))
+
+
+# a traffic mix's ``entry`` -> (the one policy, or None; the ahead-of-time
+# compile; the dispatch), from the runner, the scenario, the lanes and
+# their stacked fabric thresholds
+ENTRIES = {"run_batch": _entry_run_batch,
+           "run_policy_axis": _entry_run_policy_axis}
+
+
 class Program:
-    """The cell's scenario, built by the program from the configuration,
+    """The cell's scenario, built by the program from the configuration
+    (through the configuration's scenario module, found under ``bench``),
     and the one call the window drives."""
 
-    def __init__(self, config: dict, mix: dict, lanes: list):
-        from repro.core.collectives import get_collective
+    def __init__(self, config: dict, mix: dict, lanes: list,
+                 bench: str = BENCH):
+        if mix["entry"] not in ENTRIES:
+            raise ValueError(f"unknown entry {mix['entry']!r}")
         from repro.core.engine import EngineConfig
         from repro.core.sweep import SweepRunner
-        from repro.core.topology import clos
 
-        f, col = config["fabric"], config["collective"]
         e, k = config["engine"], config["fabric_knobs"]
-        self.topo = clos(
-            n_racks=f["n_racks"], nodes_per_rack=f["nodes_per_rack"],
-            gpus_per_node=f["gpus_per_node"], n_spines=f["n_spines"],
-            nic_bw=f["nic_gbit_s"] * 1e9 / 8, nic_lat=f["nic_latency_s"],
-            nv_bw=f["nvlink_gbyte_s"] * 1e9, nv_lat=f["nvlink_latency_s"])
-        self.sched = get_collective(col["kind"])(
-            self.topo, list(range(self.topo.n_gpus)), float(col["bytes"]),
-            n_chunks=int(col["n_chunks"]))
+        self.topo, self.sched = modules.scenario(config, bench).build(config)
         self.cfg = EngineConfig(
             dt=e["dt"], max_steps=e["max_steps"],
             max_extends=e["max_extends"], queue_stride=e["queue_stride"],
@@ -127,20 +155,18 @@ class Program:
         self.n_lanes = len(lanes)
         fab = {k: np.asarray([getattr(ln, k) for ln in lanes], np.float32)
                for k in ("kmin", "kmax", "xoff")}
-        topo, sched, runner = self.topo, self.sched, self.runner
-        if mix["entry"] != "run_batch":
-            raise ValueError(f"unknown entry {mix['entry']!r}")
-        pols = {ln.policy for ln in lanes}
-        if len(pols) != 1:
-            raise ValueError("run_batch lanes share one policy")
-        pol = pols.pop()
-        params = {key: np.asarray([ln.params[key] for ln in lanes],
-                                  np.float32)
-                  for key in lanes[0].params}
-        self.compile = lambda: runner.compile_batch(
-            topo, sched, pol, params, stacked_fabric=fab)
-        self.dispatch = lambda: runner.run_batch(
-            topo, sched, pol, params, stacked_fabric=fab)
+        self.policy, self.compile, self.dispatch = ENTRIES[mix["entry"]](
+            self.runner, self.topo, self.sched, lanes, fab)
+
+    def kernel_policy(self) -> str | None:
+        """The cell's one policy where the engine-step kernel runs its
+        update (stages 1-2), else None."""
+        from repro.core.cc import get_policy
+        from repro.core.engine import effective_step_impl
+        if self.policy is None or effective_step_impl(
+                get_policy(self.policy), self.cfg) != "pallas":
+            return None
+        return self.policy
 
     @property
     def lanes_per_device(self) -> int:
@@ -162,15 +188,17 @@ def lane_steps(batch, dt: float, budget: int) -> list[int]:
             if batch.finished[i] else budget for i in range(batch.n)]
 
 
-def reference_lanes(config: dict, lanes: list,
-                    workers: int | None = None) -> list[dict]:
-    """Every lane through the plain reference, one lane per task in a pool
-    of worker processes (spawned: they import no JAX), in lane order.  The
-    pool is shut down, and every worker has ended, before this returns."""
+def reference_lanes(config: dict, lanes: list, workers: int | None = None,
+                    bench: str = BENCH) -> list[dict]:
+    """Every lane through the configuration's plain reference, one lane
+    per task in a pool of worker processes (spawned: they import no JAX),
+    in lane order.  The pool is shut down, and every worker has ended,
+    before this returns."""
     n = min(len(lanes), workers or os.cpu_count() or 1)
     with cf.ProcessPoolExecutor(
             n, mp_context=multiprocessing.get_context("spawn")) as pool:
-        futs = [pool.submit(reference.run_lane, config, ln) for ln in lanes]
+        futs = [pool.submit(modules.reference_lane, bench, config, ln)
+                for ln in lanes]
         return [f.result() for f in futs]
 
 
@@ -199,7 +227,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     dt = float(e["dt"])
     budget = int(e["max_steps"]) * (int(e["max_extends"]) + 1)
     lanes = make_lanes(mix, seed)
-    prog = Program(config, mix, lanes)
+    prog = Program(config, mix, lanes, cell["bench"])
     with backend_compiles() as cold:
         t0 = time.perf_counter()
         prog.compile()
@@ -245,18 +273,21 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         if not tr:
             raise RuntimeError("the trace holds no device operation inside "
                                "the dispatch span")
-        pol = lanes[0].policy
-        n_state = len(reference.POLICIES[pol]().init(reference_ctx(
-            prog.sched.n_flows)))
         run = {"trace": tr, "lane_steps": steps,
-               "peaks": peaks(devices[0].device_kind),
-               "stage12_bytes": stage12_bytes(
-                   prog.sched.n_flows, reference.MAXHOP, n_state,
-                   prog.lanes_per_device)}
-        flops = stage12_flops(prog.sched.n_flows, reference.MAXHOP, pol,
-                              prog.lanes_per_device)
-        say(f"stages 1-2 per call: {run['stage12_bytes']} bytes, "
-            f"{flops} FLOP")
+               "peaks": peaks(devices[0].device_kind)}
+        pol = prog.kernel_policy()
+        if pol is not None:
+            # the work of stages 1-2, counted from the reference's shapes
+            ref = modules.reference(config, cell["bench"])
+            n_state = len(ref.POLICIES[pol]().init(reference_ctx(
+                prog.sched.n_flows)))
+            run["stage12_bytes"] = stage12_bytes(
+                prog.sched.n_flows, ref.MAXHOP, n_state,
+                prog.lanes_per_device)
+            flops = stage12_flops(prog.sched.n_flows, ref.MAXHOP, pol,
+                                  prog.lanes_per_device)
+            say(f"stages 1-2 per call: {run['stage12_bytes']} bytes, "
+                f"{flops} FLOP")
         for m in cell["per_layer"]:
             v = read_metric(m["name"], run)
             if v is not None:
@@ -274,7 +305,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     # correctness: every lane of every dispatch against the reference
     del prog
     t0 = time.perf_counter()
-    wants = reference_lanes(config, lanes, workers)
+    wants = reference_lanes(config, lanes, workers, cell["bench"])
     say(f"reference: {len(lanes)} lanes in {time.perf_counter() - t0} s "
         f"(steps {[w['steps'] for w in wants]})")
     readings = [check.lane_numbers(Program.lane(b, i), want, dt, budget)

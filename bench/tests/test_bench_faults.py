@@ -16,6 +16,7 @@ from bench.tests.cells import ROOT, small_cell
 from repro.core import engine, sweep
 
 CELL = "a2a128.atlas_dcqcn"
+POLICY_AXIS = "a2a128.policy_axis"
 SEED = 3
 
 
@@ -39,7 +40,7 @@ def test_sound_run_is_correct(fresh_compiles):
     assert res["metrics"]["lane_steps_per_s"]["value"] > 0
 
 
-def test_step_that_returns_its_state_unchanged(fresh_compiles, monkeypatch):
+def _frozen_step(monkeypatch, cell):
     real = engine._make_step
 
     def frozen(*a, **k):
@@ -47,13 +48,12 @@ def test_step_that_returns_its_state_unchanged(fresh_compiles, monkeypatch):
         return lambda carry, *args: carry
 
     monkeypatch.setattr(engine, "_make_step", frozen)
-    res = _run(small_cell(CELL))
+    res = _run(small_cell(cell))
     assert res["correct"] is False
     assert res["check"]["status_mismatch"]["value"] == 1
 
 
-@pytest.mark.parametrize("seed", [1, 2, 3, 2**31 + 11])
-def test_half_of_the_batch_left_out(fresh_compiles, monkeypatch, seed):
+def _half_batch(monkeypatch, cell, seed):
     real = sweep.SweepRunner._dispatch_lanes
 
     def half(self, policy, cfg, sim, full, fab, flt, faulty, B):
@@ -68,10 +68,19 @@ def test_half_of_the_batch_left_out(fresh_compiles, monkeypatch, seed):
         return jax.tree.map(first_half, out)
 
     monkeypatch.setattr(sweep.SweepRunner, "_dispatch_lanes", half)
-    res = _run(small_cell(CELL), seed)
+    res = _run(small_cell(cell), seed)
     assert res["correct"] is False
     # every dispatch's left-out lanes fail, and only those
     assert res["failed"] == res["attempted"] // 2
+
+
+def test_step_that_returns_its_state_unchanged(fresh_compiles, monkeypatch):
+    _frozen_step(monkeypatch, CELL)
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3, 2**31 + 11])
+def test_half_of_the_batch_left_out(fresh_compiles, monkeypatch, seed):
+    _half_batch(monkeypatch, CELL, seed)
 
 
 @pytest.mark.parametrize("cell,field,alter", [
@@ -79,6 +88,8 @@ def test_half_of_the_batch_left_out(fresh_compiles, monkeypatch, seed):
     ("ring128_ar.atlas_dcqcn", "t_finish", lambda a, dt: a + 100 * dt),
     # PAUSE frames miscounted by a tenth
     (CELL, "pause_count", lambda a, dt: a * 1.1),
+    # finish times reported 1000 steps late (past the policy axis's limit)
+    (POLICY_AXIS, "t_finish", lambda a, dt: a + 1000 * dt),
 ])
 def test_answer_altered_where_produced(fresh_compiles, monkeypatch, cell,
                                        field, alter):
@@ -92,6 +103,16 @@ def test_answer_altered_where_produced(fresh_compiles, monkeypatch, cell,
     monkeypatch.setattr(sweep.SweepRunner, "run_batch", altered)
     res = _run(small_cell(cell))
     assert res["correct"] is False
+
+
+@pytest.mark.parametrize("fault", ["frozen_step", "half_batch"])
+def test_policy_axis_faults(fresh_compiles, monkeypatch, fault):
+    """The same faults under the stacked policy axis (its dispatch is
+    ``run_policy_axis``, which runs the batch through the same path)."""
+    if fault == "frozen_step":
+        _frozen_step(monkeypatch, POLICY_AXIS)
+    else:
+        _half_batch(monkeypatch, POLICY_AXIS, 2**31 + 12)
 
 
 def test_no_tpu_no_result():

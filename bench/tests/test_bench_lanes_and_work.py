@@ -35,7 +35,7 @@ def test_seed_zero_is_the_committed_atlas_grid():
                                                float(r["xoff"]))
 
 
-@pytest.mark.parametrize("mix", ["atlas_dcqcn"])
+@pytest.mark.parametrize("mix", ["atlas_dcqcn", "policy_axis"])
 def test_seed_draws_repeat_and_stay_in_range(mix):
     m = _mix(mix)
     grid = make_lanes(m, 0)
@@ -43,14 +43,59 @@ def test_seed_draws_repeat_and_stay_in_range(mix):
     a, b = make_lanes(m, seed), make_lanes(m, seed)
     assert a == b and a != make_lanes(m, seed + 1)
     lo, hi = m["seed_factor"]
+    seeded = m.get("seeded_params", {})
     for g, ln in zip(grid, a):
         assert ln.policy == g.policy
         for k in ("kmin", "kmax", "xoff"):
             assert lo * 0.999 <= getattr(ln, k) / getattr(g, k) <= hi * 1.001
         for k, v in ln.params.items():
-            key = m["key_param"][ln.policy]
-            assert key["lo"] <= v <= key["hi"]
+            if ln.policy in seeded:
+                assert abs(v) <= seeded[ln.policy]["scale"]
+            else:
+                key = m["key_param"][ln.policy]
+                assert key["lo"] <= v <= key["hi"]
     assert [ln.policy for ln in a] == [ln.policy for ln in grid]
+
+
+def test_atlas_lanes_are_what_they_were():
+    """The seeded-parameter stream leaves a mix without one exactly as the
+    generator gave it before (lanes recorded from that generator)."""
+    with open(os.path.join(ROOT, "bench", "tests", "data",
+                           "atlas_dcqcn_lanes.json")) as f:
+        recorded = json.load(f)["lanes"]
+    m = _mix("atlas_dcqcn")
+    for seed, rows in recorded.items():
+        got = [[ln.policy, ln.params, ln.kmin, ln.kmax, ln.xoff]
+               for ln in make_lanes(m, int(seed))]
+        assert got == rows, seed
+
+
+def test_policy_axis_lanes():
+    """Every registered policy once, at its defaults, on the simulator's
+    fabric defaults; the mlp lane carries exactly the 38 weights of the
+    program's net, drawn from the seed inside the spec's bounds."""
+    from repro.core.cc import ALL_POLICIES, get_policy
+    spec = get_policy("mlp").spec
+    weight_keys = tuple(k for k in spec if k not in ("out_gain", "loss_cut"))
+    m = _mix("policy_axis")
+    draw = m["seeded_params"]["mlp"]
+    assert tuple(draw["keys"]) == weight_keys == reference.MLP_WEIGHTS
+    assert all(spec[k].lo <= -draw["scale"] and draw["scale"] <= spec[k].hi
+               for k in weight_keys)
+    seeds = (0, 7, 2**31 + 99)
+    runs = [make_lanes(m, s) for s in seeds]
+    for lanes in runs:
+        assert [ln.policy for ln in lanes] == list(ALL_POLICIES)
+        for ln in lanes:
+            assert ln.params == {} or ln.policy == "mlp"
+            for k, grid in zip(("kmin", "kmax", "xoff"), (400e3, 1.6e6, 1e6)):
+                assert 0.95 * 0.999 <= getattr(ln, k) / grid <= 1.05 * 1.001
+        w = lanes[-1].params
+        assert tuple(w) == weight_keys
+        assert all(np.float32(v) == v for v in w.values())
+    weights = [np.array(list(r[-1].params.values())) for r in runs]
+    assert not np.array_equal(weights[0], weights[1])
+    assert not np.array_equal(weights[1], weights[2])
 
 
 def test_stage12_bytes_at_the_first_cells_shapes():

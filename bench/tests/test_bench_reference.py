@@ -4,7 +4,7 @@ import jax
 import numpy as np
 import pytest
 
-from bench import check, reference
+from bench import check, modules, reference
 from bench.lanes import make_lanes
 from bench.run import Program
 from bench.tests.cells import small_pair
@@ -15,43 +15,45 @@ def test_routes_match_the_programs_schedule():
         cell = small_pair(config, "atlas_dcqcn")
         lanes = make_lanes(cell["mix"], 0)
         prog = Program(cell["config"], cell["mix"], lanes)
-        _, flows = reference.build_scenario(cell["config"])
+        _, flows = modules.reference(cell["config"]).build_scenario(
+            cell["config"])
         np.testing.assert_array_equal(flows.path, prog.sched.path)
         np.testing.assert_array_equal(flows.size, prog.sched.size)
         np.testing.assert_array_equal(flows.dep, prog.sched.dep)
 
 
-def _one_policy(policy: str) -> dict:
-    """A mix of one lane: ``policy`` at its defaults, at the fabric's."""
+def _one_policy(policy: str, seeded: dict) -> dict:
+    """A mix of one lane: ``policy`` at its defaults (the learned one on
+    seeded weights), at the fabric's."""
     return {"entry": "run_batch", "mesh": None, "policies": [policy],
             "key_param": {}, "param_span": [1.0],
             "fabric_points": [[400e3, 1.6e6, 1e6]],
-            "seed_factor": [0.95, 1.05]}
+            "seed_factor": [0.95, 1.05], "seeded_params": seeded}
 
 
 @pytest.mark.parametrize("config,traffic,policy", [
     ("ring128_ar", "atlas_dcqcn", None),
     ("a2a128", "atlas_dcqcn", None),
+    ("a2a128", "policy_axis", None),
 ] + [("a2a128", None, p) for p in sorted(reference.POLICIES)
      if p != "dcqcn"])
 def test_every_lane_agrees_with_the_reference(config, traffic, policy):
-    """Every lane of the mix, and every other policy the reference has."""
+    """Every lane of the mix, and every other policy the reference has
+    (``policy_axis``: all 8 in one stacked dispatch, mlp on seeded
+    weights)."""
     cell = small_pair(config, traffic or "atlas_dcqcn")
     if policy:
-        cell["mix"] = _one_policy(policy)
+        cell["mix"] = _one_policy(policy, small_pair(
+            config, "policy_axis")["mix"]["seeded_params"])
     lanes = make_lanes(cell["mix"], 987654321)
     prog = Program(cell["config"], cell["mix"], lanes)
     batch = prog.dispatch()
     e = cell["config"]["engine"]
     budget = e["max_steps"] * (e["max_extends"] + 1)
-    fab, flows = reference.build_scenario(cell["config"])
+    ref = modules.reference(cell["config"])
     readings = []
     for i, ln in enumerate(lanes):
-        want = reference.simulate(
-            fab, flows, ln.policy, ln.params,
-            dict(cell["config"]["fabric_knobs"], kmin=ln.kmin, kmax=ln.kmax,
-                 xoff=ln.xoff), e)
-        want["deadlocked"] = want["deadlock_step"] >= 0
+        want = ref.run_lane(cell["config"], ln)
         assert want["finished"]
         readings.append(check.lane_numbers(Program.lane(batch, i), want,
                                            e["dt"], budget))
