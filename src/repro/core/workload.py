@@ -15,7 +15,7 @@ communication =  iteration_time - total_compute  is the reported metric.
 from __future__ import annotations
 
 import dataclasses
-
+import zlib
 
 from repro.core.collectives import Schedule, ScheduleBuilder, _direct_phase
 from repro.core.engine import EngineConfig, simulate
@@ -87,10 +87,14 @@ def build_dlrm_iteration(topo: Topology, gpus: list,
 def _add_a2a(b, gpus, total, n_chunks, dep, tag):
     P = len(gpus)
     per_pair = total / n_chunks / P
+    # the ECMP salt is the tag's CRC-32, the same in every process
+    # (``hash`` of a str is salted per process by PYTHONHASHSEED)
+    base = zlib.crc32(tag.encode()) % 65536
     prev = dep
     for c in range(n_chunks):
         g = b.new_group(f"{tag}_c{c}")
-        _direct_phase(b, gpus, per_pair, g, prev, 0.0, salt=hash(tag) % 65536 + c * 104729)
+        _direct_phase(b, gpus, per_pair, g, prev, 0.0,
+                      salt=base + c * 104729)
         prev = g
     # umbrella group: completion of the last chunk == collective done
     return prev
