@@ -7,8 +7,9 @@ one executable per ``(policy logic, EngineConfig, static plan)``.
 ``SweepRunner`` adds the pieces for running *many* scenarios fast:
 
 * **shape buckets** — flow/group counts are padded up to the next power of
-  two (inert padding, see ``engine._prep``), so schedules of similar size
-  share one compiled executable instead of retracing per scenario;
+  two, and above ``_FINE_BUCKETS_ABOVE`` flows to the next eighth of an
+  octave (inert padding, see ``engine._prep``), so schedules of similar
+  size share one compiled executable instead of retracing per scenario;
 * **vmap batching** — ``run_batch`` stacks CC parameter pytrees *and*
   ``FabricParams`` leaves (ECN kmin/kmax/pmax, PFC xoff/xon) of one policy
   family on a leading axis and runs the whole population in a single
@@ -92,8 +93,22 @@ def _resolve(policy) -> Policy:
     return cc_mod.get_policy(policy) if isinstance(policy, str) else policy
 
 
+# above this many entries a bucket is an eighth of an octave, not a whole
+# one: padding stays under 12.5% where every step's per-flow work dwarfs
+# the compiles that finer buckets cost (at most 8 per octave)
+_FINE_BUCKETS_ABOVE = 32768
+
+
 def _bucket(n: int, lo: int = 32) -> int:
-    return max(lo, _next_pow2(max(n, 1)))
+    """Padded size of an axis of ``n`` entries: the next power of two (at
+    least ``lo``) up to ``_FINE_BUCKETS_ABOVE``, and above it the next
+    multiple of that power's eighth, a multiple of the kernel's 8 x 128
+    tile."""
+    p = max(lo, _next_pow2(max(n, 1)))
+    if p <= _FINE_BUCKETS_ABOVE:
+        return p
+    step = p // 8
+    return -(-n // step) * step
 
 
 @dataclasses.dataclass
@@ -1188,10 +1203,13 @@ class SweepRunner:
         """
         self._calls += 1
         call = self._calls
-        with _span("sweep.inputs", call):
+        with _span("sweep.inputs", call) as span:
             policy, cfg, sim, full, fab, flt, faulty, B = self._batch_inputs(
                 topo, sched, policy, stacked_params, stacked_fabric,
                 fabric_params, cc_params, cfg, stacked_fault, fault_spec)
+            # how much of the dispatch's per-flow work is inert padding
+            span.set_metadata(flows=sim.plan.n_flows,
+                              flows_padded=sim.plan.n_flows_pad)
         out = self._dispatch_lanes(policy, cfg, sim, full, fab, flt,
                                    faulty, B)
         with _span("sweep.results", call):

@@ -1,6 +1,7 @@
 """The engine's trace tags: the named scopes of the step's stages and of the
 loop's lane gate in the compiled executable, the Pallas kernel's stable
-name, and ``BatchResults.steps_run``.
+name, ``BatchResults.steps_run``, and the flow counts on the sweep's
+``sweep.inputs`` span.
 
 The benchmark reads the scopes from the executable's op metadata
 (``bench/trace.py`` ``op_stages``) and the loop count from
@@ -9,6 +10,7 @@ paths (the Pallas one in interpret mode), lossless and lossy.
 """
 import re
 
+import jax
 import numpy as np
 import pytest
 
@@ -93,3 +95,38 @@ def test_steps_run_is_the_loop_count():
     one = SweepRunner(cfg).run_batch(topo, sched, "dcqcn",
                                      {"rai_frac": RAI[:1]})
     assert one.steps_run.tolist() == [serial[0]]
+
+
+class _Recorded:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records each span's
+    name and its arguments, given at the start or set inside it."""
+    spans: list = []
+
+    def __init__(self, name, **kwargs):
+        self.kwargs = dict(kwargs)
+        _Recorded.spans.append((name, self.kwargs))
+
+    def set_metadata(self, **kwargs):
+        self.kwargs.update(kwargs)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_inputs_span_carries_the_flow_counts(monkeypatch):
+    topo, sched = _scenario()
+    monkeypatch.setattr(_Recorded, "spans", [])
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Recorded)
+    SweepRunner(_cfg()).run_batch(topo, sched, "dcqcn", {"rai_frac": RAI})
+    inputs = [kw for name, kw in _Recorded.spans if name == "sweep.inputs"]
+    assert inputs == [{"batch": 1, "flows": sched.n_flows,
+                       "flows_padded": 32}]
+    # the other spans keep their arguments
+    assert {name for name, _ in _Recorded.spans} >= {
+        "sweep.dispatch", "sweep.pull", "sweep.results"}
+    for name, kw in _Recorded.spans:
+        if name != "sweep.inputs":
+            assert "flows" not in kw
